@@ -74,6 +74,7 @@ def run():
     for P in [1, 2, 4, 8, 16]:
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={P}"
+        env["JAX_PLATFORMS"] = "cpu"      # virtual CPU devices, never a chip
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
         proc = subprocess.run([sys.executable, "-c", _CHILD % P], env=env,
                               capture_output=True, text=True, timeout=560)

@@ -469,14 +469,13 @@ def run_entry(entry: dict, profile: Profile):
     record is the JSON-able schedule trace (None when tracing failed).
     Never raises: failures surface as CA300."""
     import jax
-    from jax.experimental import enable_x64
 
     skip = set(entry.get("skip") or ())
     active = {r for r in profile.rules if r.startswith("CA3")} - skip
     if not active:
         return [], None
     try:
-        with enable_x64():
+        with jax.enable_x64(True):
             spec = entry["build"]()
             ctx = spec.get("ctx") or nullcontext
             fn, args = spec["fn"], tuple(spec.get("args", ()))

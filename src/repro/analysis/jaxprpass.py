@@ -191,14 +191,13 @@ def run_entry(entry: dict, profile: Profile) -> list:
     """Trace + check one manifest entry.  Never raises: failures surface
     as CA200 findings so one broken entry can't mask the rest."""
     import jax
-    from jax.experimental import enable_x64
 
     findings = []
     skip = set(entry.get("skip") or ())
     active = ({"CA201", "CA202", "CA203"} & profile.rules) - skip
     if {"CA201", "CA203"} & active:
         try:
-            with enable_x64():
+            with jax.enable_x64(True):
                 spec = entry["build"]()
                 ctx = spec.get("ctx") or nullcontext
                 fn, args = spec["fn"], tuple(spec.get("args", ()))
@@ -218,7 +217,7 @@ def run_entry(entry: dict, profile: Profile) -> list:
             findings.extend(check_collective_axes(entry, jaxpr))
     if "CA202" in active and entry.get("reuse") is not None:
         try:
-            with enable_x64():
+            with jax.enable_x64(True):
                 findings.extend(check_reuse(entry))
         except Exception as e:           # noqa: BLE001
             findings.append(_error_finding(entry, "reuse", e))

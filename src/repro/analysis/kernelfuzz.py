@@ -94,12 +94,12 @@ def run_case(entry: dict, cfg: dict, *, seed: int = 0) -> list:
     """Fuzz one (entry, config) pair under enable_x64.  Returns a list
     of :class:`FuzzResult` (one per compared output).  Never raises: a
     crashed builder surfaces as a single failed result."""
-    from jax.experimental import enable_x64
+    import jax
 
     label = cfg.get("label", "?")
     rng = case_rng(seed, entry["name"], label)
     try:
-        with enable_x64():
+        with jax.enable_x64(True):
             cases = entry["fuzz"](cfg, rng)
             results = [_compare(entry, label, name, got, want, tol)
                        for name, got, want, tol in cases]

@@ -287,9 +287,8 @@ def check_accumulator(entry: dict) -> list:
     jaxprs — the interpret-mode pallas_call body traces as jax ops — for
     float64 values narrowing to f32/f16/bf16."""
     import jax
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = entry["trace"]()
         fn, args = spec["fn"], tuple(spec.get("args", ()))
         kwargs = dict(spec.get("kwargs", {}))

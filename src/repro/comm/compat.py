@@ -1,41 +1,25 @@
-"""jax version-compatibility shims.
+"""The one module through which the repo reaches jax's sharding API.
 
-The repo is written against the current jax API (``jax.shard_map``,
-``jax.set_mesh``, ``jax.make_mesh(..., axis_types=...)``).  Older jax
-releases (<= 0.4.x, the version baked into some CPU test containers) expose
-the same functionality under different names:
+Everything in-repo imports ``shard_map`` / ``use_mesh`` / ``make_mesh`` /
+``psum`` from here instead of touching ``jax.*`` directly, so that:
 
-  * ``jax.shard_map(check_vma=...)``  -> ``jax.experimental.shard_map``'s
-    ``shard_map(check_rep=...)``
-  * ``with jax.set_mesh(mesh): ...``  -> ``with mesh: ...`` (Mesh is itself
-    a context manager)
-  * ``jax.make_mesh(shape, axes, axis_types=...)`` -> same without
-    ``axis_types``
-
-Everything in-repo should import ``shard_map`` / ``use_mesh`` / ``make_mesh``
-from here instead of touching ``jax.*`` directly so a single module absorbs
-the API skew.
+  * a future jax API change is absorbed in one place;
+  * every collective posted outside the 1.5D layer is greppable here and
+    can be announced to the ``repro.obs.commwatch`` watcher;
+  * the AST checks (CA1xx) can flag raw ``jax.shard_map`` use elsewhere.
 """
 from __future__ import annotations
 
 import jax
+from jax import lax
 
 __all__ = ["shard_map", "use_mesh", "make_mesh", "axis_size",
            "get_abstract_mesh", "psum", "set_collective_watcher"]
 
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 #: collective-wrapper watcher (``repro.obs.commwatch``): when set, every
@@ -58,43 +42,29 @@ def set_collective_watcher(watcher):
 def psum(x, axis_name):
     """``lax.psum`` re-export: the blessed spelling outside the collective
     layer (``comm/``, ``core/distributed.py``), so every cross-device
-    reduction in model/data code is greppable here and covered by the
-    same skew-absorbing module as ``shard_map``."""
-    from jax import lax
+    reduction in model/data code is greppable here."""
     if _COLLECTIVE_WATCHER is not None:
         _COLLECTIVE_WATCHER.on_collective("psum", axis_name, x)
     return lax.psum(x, axis_name)
 
 
 def axis_size(name):
-    """``lax.axis_size`` fallback: psum of a literal 1 resolves to the axis
-    size at trace time on older jax."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
+    """Size of the named mesh axis (static at trace time)."""
+    return lax.axis_size(name)
 
 
 def use_mesh(mesh):
     """Context manager installing ``mesh`` as the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # legacy Mesh objects are context managers themselves
+    return jax.set_mesh(mesh)
 
 
 def get_abstract_mesh():
-    """The ambient mesh installed by :func:`use_mesh` (``.empty`` when none).
-
-    New jax exposes it as ``jax.sharding.get_abstract_mesh()``; on older
-    releases the ``with mesh:`` context lives in thread resources."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as mesh_lib
-    return mesh_lib.thread_resources.env.physical_mesh
+    """The ambient mesh set by :func:`use_mesh` (``.empty`` when none)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def make_mesh(axis_shapes, axis_names, devices=None):
-    """``jax.make_mesh`` with explicit Auto axis types where supported.
+    """``jax.make_mesh`` with explicit Auto axis types.
 
     With an explicit ``devices`` sequence the mesh is built directly from
     ``jax.sharding.Mesh`` in the GIVEN order — ``jax.make_mesh`` may
@@ -104,8 +74,6 @@ def make_mesh(axis_shapes, axis_names, devices=None):
         import numpy as np
         devs = np.asarray(devices).reshape(tuple(axis_shapes))
         return jax.sharding.Mesh(devs, tuple(axis_names))
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs = {"axis_types": (jax.sharding.AxisType.Auto,) * len(axis_names)}
-    else:
-        kwargs = {}
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))

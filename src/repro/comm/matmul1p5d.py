@@ -103,7 +103,7 @@ def rot_gather_local(r_blk, f_loc, grid: Grid1p5D, *, n_r: int,
         if ring == "x":
             tile = matops.matmul(cur, f_loc, mask=msk, policy=policy)
         else:
-            tile = f_loc @ cur
+            tile = matops.dot(f_loc, cur)
         return (nxt, nmsk), tile
 
     (_, _), tiles = lax.scan(body, (cur0, msk0), None,
@@ -184,7 +184,7 @@ def omega_xt_local(omega_rows, xt_loc, grid: Grid1p5D, *, scale=1.0,
         cols = lax.dynamic_slice(omega_rows, (jnp.int32(0), v * blk_x),
                                  (blk_om, blk_x))
         if omega_mask is None:
-            acc = acc + cols @ cur
+            acc = acc + matops.dot(cols, cur)
         else:
             mcols = lax.dynamic_slice(
                 omega_mask, (jnp.int32(0), v * mcols_blk),

@@ -257,6 +257,8 @@ class BatchRunStats(NamedTuple):
     order: tuple           # lane processing order (difficulty sort)
     gemm: str = "xla"      # flat-step gemm backend (BATCH_GEMMS)
     pilot_lane: int = -1   # warm-start pilot lane index (-1 = none)
+    kernel: str | None = None   # "pathstep" when the fused megakernel ran
+                                # the flat steps (None: the jnp trial)
 
     @property
     def lane_steps(self) -> int:
@@ -410,6 +412,14 @@ def _init_lanes(arr, ridge, omega0, *, variant: str, stacked: bool,
     )
 
 
+def _uses_megakernel(use_pallas: bool, variant: str, penalty: PenaltySpec,
+                     stacked: bool) -> bool:
+    """Whether the flat step runs the fused path-step kernel: Cov, shared
+    data and a soft-threshold-family penalty (see ``kernels.pathstep``)."""
+    return use_pallas and variant == "cov" and penalty.pallas_ok \
+        and not stacked
+
+
 @partial(jax.jit, static_argnames=_CHUNK_STATICS)
 def _path_chunk(arr, ridge, lanes: _Lanes, penalty: PenaltySpec, *,
                 variant: str, tol: float, max_iters: int, max_ls: int,
@@ -434,8 +444,7 @@ def _path_chunk(arr, ridge, lanes: _Lanes, penalty: PenaltySpec, *,
     c = lanes.g_val.shape[0]
     dtype = lanes.omega.dtype
     pleaves, ptree = jax.tree_util.tree_flatten(penalty)
-    pallas = (use_pallas and variant == "cov" and penalty.pallas_ok
-              and not stacked)
+    pallas = _uses_megakernel(use_pallas, variant, penalty, stacked)
 
     def trials_jnp(lanes):
         def one(om, aux, gv, tau, arr_i, l2, *pl):
@@ -465,7 +474,7 @@ def _path_chunk(arr, ridge, lanes: _Lanes, penalty: PenaltySpec, *,
         cand, stats = kops.fused_path_step(
             lanes.omega, lanes.aux, tau, lam1, lam2, weights=weights)
         dot_dg, dot_dd = stats[:, 0], stats[:, 1]
-        aux_c = cand @ arr
+        aux_c = matops.dot(cand, arr)
         g_c = jax.vmap(
             lambda om, aux, l2: ops.g_of(om, aux, {"lam2": l2}))(
             cand, aux_c, jnp.asarray(lam2, dtype))
@@ -813,7 +822,9 @@ def _solve_compact(arr, spec, ridge, omega0, *, variant, tol, max_iters,
         schedule="compact", n_lanes=b, chunk=chunk, segments=segments,
         waves=len(waves), occupancy=tuple(occupancy),
         capacities=tuple(capacities), order=tuple(int(i) for i in order),
-        gemm=gemm, pilot_lane=pilot_lane)
+        gemm=gemm, pilot_lane=pilot_lane,
+        kernel="pathstep" if _uses_megakernel(use_pallas, variant, spec,
+                                              stacked) else None)
     return res, stats
 
 

@@ -43,7 +43,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..comm import matmul1p5d as mm
 from ..comm import sparse1p5d as sp
-from ..comm.compat import axis_size, shard_map, use_mesh
+from ..comm.compat import axis_size, shard_map
 from ..comm.grid import Grid1p5D
 from . import matops
 from .costmodel import Machine, ProblemShape, tune
@@ -372,9 +372,9 @@ def _spec_partition(spec: PenaltySpec, mat_spec):
 
 
 #: dispatch-observer hook (``repro.obs.commwatch``): when set, the driver
-#: announces every jit dispatch (inside ``use_mesh``, before the call) and
-#: its result.  The observer may re-trace the closure (``jax.make_jaxpr``)
-#: but must not compile or execute anything — the solve itself is untouched.
+#: announces every jit dispatch (before the call) and its result.  The
+#: observer may re-trace the closure (``jax.make_jaxpr``) but must not
+#: compile or execute anything — the solve itself is untouched.
 _DISPATCH_OBSERVER = None
 
 
@@ -462,11 +462,10 @@ def fit_cov(
                        in_specs=(SPEC_XCOL, spec_parts, SPEC_XCOL),
                        out_specs=ProxResult(*specs), check_vma=False)
         args = (s, spec, _pad_omega0(omega0, p, p_pad, dtype))
-    with use_mesh(mesh):
-        res = _dispatch("cov", fn, args, grid,
-                        {"p": p, "p_pad": p_pad, "n": None,
-                         "dtype": jnp.dtype(dtype).name,
-                         "sparse": ops.prox_stats is not None})
+    res = _dispatch("cov", fn, args, grid,
+                    {"p": p, "p_pad": p_pad, "n": None,
+                     "dtype": jnp.dtype(dtype).name,
+                     "sparse": ops.prox_stats is not None})
     return FitResult(res.omega[:p, :p], res.iters, res.ls_total,
                      res.converged, res.g_final, "cov", grid,
                      res.block_density, res.stalled)
@@ -529,11 +528,10 @@ def fit_obs(
                        in_specs=(SPEC_XCOL, spec_parts, SPEC_OM),
                        out_specs=ProxResult(*specs), check_vma=False)
         args = (x, spec, _pad_omega0(omega0, p, p_pad, dtype))
-    with use_mesh(mesh):
-        res = _dispatch("obs", fn, args, grid,
-                        {"p": p, "p_pad": p_pad, "n": n,
-                         "dtype": jnp.dtype(dtype).name,
-                         "sparse": ops.prox_stats is not None})
+    res = _dispatch("obs", fn, args, grid,
+                    {"p": p, "p_pad": p_pad, "n": n,
+                     "dtype": jnp.dtype(dtype).name,
+                     "sparse": ops.prox_stats is not None})
     return FitResult(res.omega[:p, :p], res.iters, res.ls_total,
                      res.converged, res.g_final, "obs", grid,
                      res.block_density, res.stalled)

@@ -65,6 +65,18 @@ MASK_DTYPE = jnp.int8
 #: the f64 iterate arithmetic, and the distributed drivers psum it.
 DENSITY_DTYPE = jnp.float32
 
+#: precision of every product the solver forms (Ω-side, Gram, gradient).
+#: Stated here rather than left to the platform: a TPU's default for f32
+#: operands feeds bf16 to the matrix unit, which moves the f32 solve off
+#: the f64 reference's optimum.  HIGHEST keeps f32-accurate products; the
+#: CPU backend and f64 operands compute the same either way.
+PRECISION = lax.Precision.HIGHEST
+
+
+def dot(a, b):
+    """``a @ b`` at the solver's stated :data:`PRECISION`."""
+    return jnp.matmul(a, b, precision=PRECISION)
+
 
 class MatmulPolicy(NamedTuple):
     """Static (hashable) routing policy for Ω-side products.
@@ -157,7 +169,7 @@ def masked_matmul(a, b, mask, *, block_size: int, capacity: int):
     vals = a4[r_idx, :, c_idx, :]             # (capacity, bs, bs) gather
     vals = vals * occupied[idx][:, None, None].astype(vals.dtype)
     b3 = bp.reshape(nbc, bs, m)
-    prods = jnp.einsum("nij,njm->nim", vals, b3[c_idx])
+    prods = jnp.einsum("nij,njm->nim", vals, b3[c_idx], precision=PRECISION)
     out = jax.ops.segment_sum(prods, r_idx, num_segments=nbr)
     return out.reshape(nbr * bs, m)[:p]
 
@@ -165,14 +177,14 @@ def masked_matmul(a, b, mask, *, block_size: int, capacity: int):
 def matmul(a, b, *, mask=None, policy: MatmulPolicy | None = None):
     """The Ω-side product dispatch.
 
-    Dense ``a @ b`` when the policy is off (or no mask is available);
+    Dense :func:`dot` when the policy is off (or no mask is available);
     otherwise a ``lax.switch`` on the observed block density of ``mask``:
     density above ``policy.threshold`` falls back to the dense path, below
     it the block-gather path runs with the smallest capacity tier that
     covers the occupied blocks.  Exact either way (see ``masked_matmul``).
     """
     if policy is None or not policy.enabled or mask is None:
-        return a @ b
+        return dot(a, b)
     bs = policy.block_size
     nbr, nbc = _cdiv(a.shape[0], bs), _cdiv(a.shape[1], bs)
     if mask.shape != (nbr, nbc):
@@ -182,7 +194,7 @@ def matmul(a, b, *, mask=None, policy: MatmulPolicy | None = None):
     total = nbr * nbc
     caps = capacity_tiers(total, policy.threshold)
     if not caps:
-        return a @ b
+        return dot(a, b)
 
     # Rung selection compares INTEGER occupied-block counts against the
     # integer capacities (a float density ratio loses ulps past 2^24
@@ -200,7 +212,7 @@ def matmul(a, b, *, mask=None, policy: MatmulPolicy | None = None):
         return _sparse
 
     branches = [_make_sparse(c) for c in caps]
-    branches.append(lambda a_, b_, m_: a_ @ b_)
+    branches.append(lambda a_, b_, m_: dot(a_, b_))
     return lax.switch(ix, branches, a, b, mask)
 
 

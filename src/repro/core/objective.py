@@ -27,6 +27,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from . import matops
+
 
 def soft_threshold(z: jax.Array, alpha) -> jax.Array:
     """Elementwise soft-thresholding S_alpha(z) (paper eq. (2))."""
@@ -81,13 +83,13 @@ def gradient_from_w(omega: jax.Array, w: jax.Array, lam2) -> jax.Array:
 
 
 def full_objective_cov(omega, s, lam1, lam2):
-    w = omega @ s
+    w = matops.dot(omega, s)
     return smooth_objective_cov(omega, w, lam2) + lam1 * offdiag_l1(omega)
 
 
 def full_objective_obs(omega, x, lam1, lam2):
     n = x.shape[0]
-    y = omega @ x.T
+    y = matops.dot(omega, x.T)
     return smooth_objective_obs(omega, y, n, lam2) + lam1 * offdiag_l1(omega)
 
 

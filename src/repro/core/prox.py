@@ -366,6 +366,23 @@ def _ref_prox(z, pen, tau, data):
     return pen.prox(z, tau)
 
 
+def _ref_prox_op(use_pallas: bool) -> Callable:
+    """The prox of the single-device variants: with ``use_pallas`` the
+    fused Pallas kernel for the soft-threshold penalty family (as in the
+    distributed drivers), the jnp prox otherwise."""
+    if not use_pallas:
+        return _ref_prox
+
+    def prox(z, pen, tau, data):
+        if not pen.pallas_ok:
+            return pen.prox(z, tau)
+        from ..kernels import ops as kops
+        eye = jnp.eye(z.shape[-1], dtype=z.dtype)
+        return kops.fused_prox(z, eye, tau * pen.lam1, weights=pen.weights)
+
+    return prox
+
+
 def _ref_sparse_ops(policy: matops.MatmulPolicy, use_pallas: bool):
     """(prox_stats, mask_of, density_of) for the single-device variants.
 
@@ -413,9 +430,10 @@ def cov_ops(sparse_matmul: matops.MatmulPolicy | None = None,
     def grad_of(omega, w, data):
         return gradient_from_w(omega, w, data["lam2"])
 
+    prox = _ref_prox_op(use_pallas)
     if policy is None or not policy.enabled:
-        return VariantOps(aux_of, g_of, grad_of, _ref_dot, _ref_prox)
-    return VariantOps(aux_of, g_of, grad_of, _ref_dot, _ref_prox,
+        return VariantOps(aux_of, g_of, grad_of, _ref_dot, prox)
+    return VariantOps(aux_of, g_of, grad_of, _ref_dot, prox,
                       *_ref_sparse_ops(policy, use_pallas))
 
 
@@ -436,12 +454,13 @@ def obs_ops(sparse_matmul: matops.MatmulPolicy | None = None,
 
     def grad_of(omega, y, data):
         x = data["x"]
-        z = (y @ x) / x.shape[0]              # Z = Omega S
+        z = matops.dot(y, x) / x.shape[0]     # Z = Omega S
         return gradient_from_w(omega, z, data["lam2"])
 
+    prox = _ref_prox_op(use_pallas)
     if policy is None or not policy.enabled:
-        return VariantOps(aux_of, g_of, grad_of, _ref_dot, _ref_prox)
-    return VariantOps(aux_of, g_of, grad_of, _ref_dot, _ref_prox,
+        return VariantOps(aux_of, g_of, grad_of, _ref_dot, prox)
+    return VariantOps(aux_of, g_of, grad_of, _ref_dot, prox,
                       *_ref_sparse_ops(policy, use_pallas))
 
 
@@ -513,9 +532,10 @@ def solve_reference(
 
     ``sparse_matmul`` (a hashable :class:`repro.core.matops.MatmulPolicy`)
     routes the Ω-side product through the block-sparse dispatch once the
-    observed block density of the iterate drops below the policy threshold;
-    ``use_pallas`` additionally harvests the occupancy mask from the fused
-    Pallas prox kernel instead of a separate jnp pass.
+    observed block density of the iterate drops below the policy threshold.
+    ``use_pallas`` runs the prox through the fused Pallas kernel
+    (soft-threshold penalty family), which with ``sparse_matmul`` also
+    harvests the occupancy mask instead of a separate jnp pass.
     """
     spec = normalize_penalty(penalty, lam1, lam2)
     if spec.weights is not None:

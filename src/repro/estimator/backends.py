@@ -113,7 +113,7 @@ class Problem(NamedTuple):
         """The (p, p) sample covariance, formed on demand."""
         if self.s is not None:
             return self.s
-        return (self.x.T @ self.x) / self.n
+        return matops.dot(self.x.T, self.x) / self.n
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import batch as core_batch
+from ..core import matops
 from ..core.penalty import PenaltySpec, normalize_penalty
 from ..core.prox import ProxResult
 from .backends import Problem, _cast, _report
@@ -129,7 +130,8 @@ def fit_batch(x=None, *, s=None, lam1=None, lam2=0.0, penalty=None,
     if variant == "cov" and x is not None:
         # form the per-problem covariances in one batched einsum
         n = data.shape[1]
-        data = jnp.einsum("bni,bnj->bij", data, data) / n
+        data = jnp.einsum("bni,bnj->bij", data, data,
+                          precision=matops.PRECISION) / n
     data = _cast(data, cfg)
     b = data.shape[0]
     if penalty is not None:

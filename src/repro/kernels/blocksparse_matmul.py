@@ -94,7 +94,7 @@ def _validate_row_runs(row_idx) -> None:
 @partial(jax.jit, static_argnames=("block_n", "interpret"))
 def _blocksparse_matmul(values: jax.Array, row_idx: jax.Array,
                         col_idx: jax.Array, b: jax.Array,
-                        *, block_n: int = 256, interpret: bool = True):
+                        *, interpret: bool, block_n: int = 256):
     nb, bs, _ = values.shape
     p, m = b.shape
     lay = kernel_layout(nb, bs, p, m, block_n=block_n)
@@ -114,7 +114,7 @@ def _blocksparse_matmul(values: jax.Array, row_idx: jax.Array,
 
 def blocksparse_matmul(values: jax.Array, row_idx: jax.Array,
                        col_idx: jax.Array, b: jax.Array,
-                       *, block_n: int = 256, interpret: bool = True):
+                       *, interpret: bool, block_n: int = 256):
     """C = A @ B with A in block-CSR ((nb, bs, bs) + sorted row/col ids).
 
     b: (p, m). Returns (p, m). Requires every block-row represented at

@@ -136,7 +136,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     softcap: float | None = None, scale: float | None = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: (B, Hq, Lq, D); k, v: (B, Hkv, Lkv, D). Returns (B, Hq, Lq, D).
 
     Lq may be shorter than Lkv (chunked prefill / decode): query position i

@@ -13,16 +13,21 @@ partials stay outside.
 
 Layout: the C lanes are stacked tall — Omega and W arrive as
 ``(C * p, p)`` — and the grid is ``(C * p/bs, p/bs)`` square tiles with
-``bs`` a divisor of p.  The transposed-W term of the gradient needs tile
-``(j, i mod p/bs)`` of the SAME lane, fetched by a second BlockSpec on W
+``bs`` a divisor of p.  For the TPU lowering ``bs`` must also be a
+multiple of 128 (a tile edge that is not would be refused by Mosaic on
+the stacked operand), so a p with no such divisor raises ``ValueError``
+before dispatch; interpret mode takes any divisor.
+
+The transposed-W term of the gradient needs tile ``(j, i mod p/bs)`` of
+the SAME lane, fetched by a second BlockSpec on W
 whose index map swaps the within-lane block coordinates (no transposed
 copy of W is ever materialized).  Per-lane scalars ride in an SMEM
 ``(C, 3)`` table ``[tau, alpha = tau * lam1, lam2]`` indexed by the
 lane id ``i // (p/bs)``.
 
-Per-tile stats land in a ``(grid_m, grid_n, 128)`` lane-padded output
-(lanes 0..4 = dot_dg, dot_dd, sumsq, l1_offdiag, nnz) that the wrapper
-sum-reduces per lane; the nnz lane is the occupancy harvest.  The
+Per-tile stats land in one (8, 128) block per tile, as in ``softthresh``
+(lanes 0..4 = dot_dg, dot_dd, sumsq, l1_offdiag, nnz); the wrapper
+sum-reduces them per lane, and the nnz lane is the occupancy harvest.  The
 elementwise candidate is bit-identical to the jitted ``ref.py`` oracle
 (eager oracle dispatch fuses multiply-adds differently and can differ by
 one ulp); the stats differ from a flat ``jnp.sum`` only by tile-order
@@ -41,18 +46,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .softthresh import STATS_MIN_DTYPE, STATS_LANES
+from .softthresh import (
+    STATS_LANES,
+    STATS_MIN_DTYPE,
+    STATS_SUBLANES,
+    check_native_blocks,
+    tile_stats,
+)
 
 #: preferred square tile edge; the actual edge is the largest divisor of
-#: p not exceeding it (p itself when p is prime — interpret mode only)
+#: p not exceeding it (natively: the largest 128-multiple divisor)
 DEFAULT_BLOCK = 256
+
+#: lane width a native tile edge must be a multiple of
+LANE = 128
 
 #: stats lanes: [0]=<diff,grad> [1]=<diff,diff> [2]=||cand||_F^2
 #: [3]=off-diagonal l1 of cand [4]=tile nnz of cand
 N_STATS = 5
 
 
-def _block_edge(p: int, block: int) -> int:
+def _block_edge(p: int, block: int, native: bool = False) -> int:
+    if native:
+        for bs in range(max(block, LANE) // LANE * LANE, 0, -LANE):
+            if p % bs == 0:
+                return bs
+        raise ValueError(
+            f"fused_path_step: p={p} has no tile edge that is a multiple "
+            f"of {LANE}, so the kernel cannot lower for the TPU; run the "
+            f"batched path with use_pallas=False or pad p to a multiple "
+            f"of {LANE}")
     bs = min(block, p)
     while p % bs:
         bs -= 1
@@ -85,8 +108,9 @@ def _tile_step(scal_ref, om, w, wt, wts, c, diag):
 
 
 def _write_stats(parts, stats_ref):
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, STATS_LANES), 2)
-    stats = jnp.zeros((1, 1, STATS_LANES), stats_ref.dtype)
+    shape = (STATS_SUBLANES, STATS_LANES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    stats = jnp.zeros(shape, stats_ref.dtype)
     for k, v in enumerate(parts):
         stats = jnp.where(lane == k, v.astype(stats_ref.dtype), stats)
     stats_ref[...] = stats
@@ -124,16 +148,17 @@ def _kernel_weighted(scal_ref, om_ref, w_ref, wt_ref, wts_ref, out_ref,
 
 
 def kernel_layout(c_lanes: int, p: int, *, weighted: bool = False,
-                  block: int = DEFAULT_BLOCK) -> dict:
+                  block: int = DEFAULT_BLOCK, native: bool = False) -> dict:
     """Grid + BlockSpec geometry of the path-step ``pallas_call``.
 
     Shared by the wrapper below and the CA4xx kernel verifier (via
-    ``kernels.manifest``).  ``bs`` is the resolved tile edge (the prime-p
-    full-tile fallback of :func:`_block_edge` included) and ``gpm`` the
+    ``kernels.manifest``).  ``bs`` is the resolved tile edge (``native``
+    picks a 128-multiple for the TPU lowering; otherwise the prime-p
+    full-tile fallback of :func:`_block_edge` applies) and ``gpm`` the
     per-lane block count; the SMEM scalar table rides first in
     ``in_specs``, matching the call's operand order.
     """
-    bs = _block_edge(p, block)
+    bs = _block_edge(p, block, native)
     gpm = p // bs
     gm, gn = c_lanes * gpm, gpm
     tile = pl.BlockSpec((bs, bs), lambda i, j: (i, j))
@@ -148,9 +173,10 @@ def kernel_layout(c_lanes: int, p: int, *, weighted: bool = False,
         "in_specs": in_specs,
         "out_specs": [
             pl.BlockSpec((bs, bs), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1, STATS_LANES), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((STATS_SUBLANES, STATS_LANES), lambda i, j: (i, j)),
         ],
-        "out_shapes": ((c_lanes * p, p), (gm, gn, STATS_LANES)),
+        "out_shapes": ((c_lanes * p, p),
+                       (gm * STATS_SUBLANES, gn * STATS_LANES)),
         "bs": bs,
         "gpm": gpm,
     }
@@ -158,8 +184,8 @@ def kernel_layout(c_lanes: int, p: int, *, weighted: bool = False,
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
 def fused_path_step(omega: jax.Array, w: jax.Array, tau, lam1, lam2,
-                    *, weights=None, block: int = DEFAULT_BLOCK,
-                    interpret: bool = True):
+                    *, interpret: bool, weights=None,
+                    block: int = DEFAULT_BLOCK):
     """One fused flat step for C stacked lanes.
 
     ``omega``/``w`` are (C, p, p) iterates and their cached aux products
@@ -172,7 +198,7 @@ def fused_path_step(omega: jax.Array, w: jax.Array, tau, lam1, lam2,
     c_lanes, p, _ = omega.shape
     dtype = omega.dtype
     lay = kernel_layout(c_lanes, p, weighted=weights is not None,
-                        block=block)
+                        block=block, native=not interpret)
     bs, gpm = lay["bs"], lay["gpm"]
     gm, gn = lay["grid"]
     scal = jnp.stack([
@@ -187,6 +213,11 @@ def fused_path_step(omega: jax.Array, w: jax.Array, tau, lam1, lam2,
         jax.ShapeDtypeStruct(lay["out_shapes"][0], dtype),
         jax.ShapeDtypeStruct(lay["out_shapes"][1], stats_dtype),
     ]
+    if not interpret:
+        check_native_blocks(
+            "fused_path_step", lay["in_specs"] + lay["out_specs"],
+            [(c_lanes, 3)] + [(c_lanes * p, p)] * (len(lay["in_specs"]) - 1)
+            + list(lay["out_shapes"]))
     kw = dict(grid=lay["grid"], in_specs=lay["in_specs"],
               out_specs=lay["out_specs"], out_shape=out_shape,
               interpret=interpret)
@@ -201,5 +232,6 @@ def fused_path_step(omega: jax.Array, w: jax.Array, tau, lam1, lam2,
         cand, stats = pl.pallas_call(
             partial(_kernel_weighted, bs=bs, gpm=gpm),
             **kw)(scal, om2, w2, w2, wts.reshape(c_lanes * p, p))
-    per_lane = stats.reshape(c_lanes, gpm, gn, STATS_LANES).sum(axis=(1, 2))
+    per_lane = tile_stats(stats).reshape(
+        c_lanes, gpm, gn, STATS_LANES).sum(axis=(1, 2))
     return cand.reshape(c_lanes, p, p), per_lane[:, :N_STATS]

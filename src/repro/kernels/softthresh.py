@@ -15,8 +15,10 @@ Without it the scalar-broadcast fast path is byte-for-byte the original
 kernel — no extra HBM traffic, bit-identical output.
 
 Tiles are (block_m, block_n) VMEM blocks; the per-tile partial stats land
-in a (grid_m, grid_n, 128) output (TPU lane-padded; only lanes 0..4 carry
-data) that the wrapper reduces.
+in one (8, 128) block per tile of a (grid_m * 8, grid_n * 128) output —
+the smallest block the TPU lowering accepts (sublane and lane aligned).
+Every row of a tile's block carries the same stats and only lanes 0..4
+carry data; the wrapper reads row 0 of each block and reduces.
 """
 from __future__ import annotations
 
@@ -34,11 +36,40 @@ DEFAULT_BLOCK = (256, 256)
 # block size, stats[..., 4] > 0 IS the block-sparse dispatch mask.
 # (lane 1 stays the UNWEIGHTED |out| sum in the weighted kernel.)
 STATS_LANES = 128
+#: sublane rows of a tile's stats block (the TPU's (8, 128) minimum tile)
+STATS_SUBLANES = 8
 
 #: floor dtype of the per-tile stats output: at least f32 (counts and
 #: reductions would drift in bf16), widened to the operand dtype so an
 #: f64 interpret-mode solve keeps f64 line-search stats end to end.
 STATS_MIN_DTYPE = jnp.float32
+
+
+def check_native_blocks(kernel: str, specs, shapes) -> None:
+    """Refuse, before dispatch, a layout the TPU lowering would reject.
+
+    Mosaic needs the last two dimensions of every VMEM block to be
+    multiples of (8, 128), or equal to the array's own dimensions.
+    ``specs`` pairs with ``shapes`` (array shapes); specs without a block
+    shape (SMEM tables) are skipped."""
+    for spec, shape in zip(specs, shapes):
+        block = getattr(spec, "block_shape", None)
+        if block is None:
+            continue
+        for b, a, q in zip(tuple(block)[-2:], tuple(shape)[-2:], (8, 128)):
+            if b % q and b != a:
+                raise ValueError(
+                    f"{kernel}: block {tuple(block)} of a {tuple(shape)} "
+                    f"operand cannot lower for the TPU (the last two block "
+                    f"dims must be multiples of (8, 128) or span the "
+                    f"array); pick a 128-multiple block")
+
+
+def tile_stats(stats):
+    """(grid_m * 8, grid_n * 128) stats output -> (grid_m, grid_n, 128):
+    row 0 of each tile's (8, 128) block."""
+    gm, gn = stats.shape[0] // STATS_SUBLANES, stats.shape[1] // STATS_LANES
+    return stats.reshape(gm, STATS_SUBLANES, gn, STATS_LANES)[:, 0]
 
 
 def _write_stats(out, m, valid, stats_ref):
@@ -48,7 +79,8 @@ def _write_stats(out, m, valid, stats_ref):
     sumsq = jnp.sum(out * out)
     min_diag = jnp.min(jnp.where(is_diag, out, jnp.inf))
     nnz = jnp.sum(((out != 0.0) & valid).astype(stats_ref.dtype))
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, STATS_LANES), 2)
+    lane = jax.lax.broadcasted_iota(
+        jnp.int32, (STATS_SUBLANES, STATS_LANES), 1)
     stats = jnp.where(lane == 0, logdet, 0.0)
     stats = jnp.where(lane == 1, l1, stats)
     stats = jnp.where(lane == 2, sumsq, stats)
@@ -104,6 +136,8 @@ def kernel_layout(m: int, n: int, *, weighted: bool = False,
     here, so a layout edit is checked exactly as it ships.  ``in_specs``
     lists the SMEM alpha table first, matching the operand order of the
     call; ``out_shapes`` are the logical (unpadded) output array shapes.
+    The stats output holds one ``(STATS_SUBLANES, STATS_LANES)`` block per
+    tile (see :func:`tile_stats`).
     """
     bm = min(block[0], m)
     bn = min(block[1], n)
@@ -117,16 +151,16 @@ def kernel_layout(m: int, n: int, *, weighted: bool = False,
         "in_specs": in_specs,
         "out_specs": [
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1, STATS_LANES), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((STATS_SUBLANES, STATS_LANES), lambda i, j: (i, j)),
         ],
-        "out_shapes": ((m, n), (gm, gn, STATS_LANES)),
+        "out_shapes": ((m, n), (gm * STATS_SUBLANES, gn * STATS_LANES)),
     }
 
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
 def fused_prox_stats(z: jax.Array, diag_mask: jax.Array, alpha,
-                     *, weights=None, block=DEFAULT_BLOCK,
-                     interpret: bool = True):
+                     *, interpret: bool, weights=None,
+                     block=DEFAULT_BLOCK):
     """Returns (out, logdet, l1_offdiag, sumsq, min_diag, block_nnz).
 
     ``block_nnz`` is the (grid_m, grid_n) per-tile nonzero count of the
@@ -145,6 +179,11 @@ def fused_prox_stats(z: jax.Array, diag_mask: jax.Array, alpha,
         jax.ShapeDtypeStruct(lay["out_shapes"][0], z.dtype),
         jax.ShapeDtypeStruct(lay["out_shapes"][1], stats_dtype),
     ]
+    if not interpret:
+        check_native_blocks(
+            "fused_prox_stats", lay["in_specs"] + lay["out_specs"],
+            [(1,)] + [(m, n)] * (len(lay["in_specs"]) - 1)
+            + list(lay["out_shapes"]))
     kw = dict(grid=lay["grid"], in_specs=lay["in_specs"],
               out_specs=lay["out_specs"], out_shape=out_shape,
               interpret=interpret)
@@ -161,6 +200,7 @@ def fused_prox_stats(z: jax.Array, diag_mask: jax.Array, alpha,
         out, stats = pl.pallas_call(
             partial(_kernel_weighted, nrows=m, ncols=n), **kw,
         )(alpha_arr, z, diag_mask, w)
+    stats = tile_stats(stats)
     logdet = jnp.sum(stats[..., 0])
     l1 = jnp.sum(stats[..., 1])
     sumsq = jnp.sum(stats[..., 2])
@@ -171,7 +211,7 @@ def fused_prox_stats(z: jax.Array, diag_mask: jax.Array, alpha,
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
 def fused_prox(z: jax.Array, diag_mask: jax.Array, alpha,
-               *, weights=None, block=DEFAULT_BLOCK, interpret: bool = True):
+               *, interpret: bool, weights=None, block=DEFAULT_BLOCK):
     """Prox only (no stats) — the distributed drivers' inner step."""
     return fused_prox_stats(z, diag_mask, alpha, weights=weights,
                             block=block, interpret=interpret)[0]

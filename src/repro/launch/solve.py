@@ -115,6 +115,9 @@ def main(argv=None):
     if args.adaptive and not args.path:
         ap.error("--adaptive needs --path (it refits a lam1 grid)")
 
+    from .cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.from_gram:
         return _solve_from_gram(args)
 

@@ -100,6 +100,9 @@ def cmd_reconcile(args) -> int:
     import numpy as np
 
     import jax
+    # virtual CPU devices only: the host-platform device count above does
+    # not apply to an accelerator, which this check must never claim
+    jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_enable_x64", True)
 
     from ..comm.grid import Grid1p5D

@@ -402,7 +402,7 @@ class CommWatch:
 
     # -- core.distributed dispatch-observer protocol ---------------------
     def on_dispatch(self, variant: str, fn, args, grid, meta: dict):
-        """Called inside ``use_mesh`` right before the driver's jit call.
+        """Called right before the driver's jit call.
         ``make_jaxpr`` only traces — no compile, no numeric effect."""
         import jax
 

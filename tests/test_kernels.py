@@ -196,3 +196,28 @@ def test_mea_attention_matches_flash_oracle(rng):
     o2 = ops.flash_attention(q, k, v, block_q=64, block_k=64)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("fn", [
+    "softthresh.fused_prox_stats", "softthresh.fused_prox",
+    "pathstep.fused_path_step", "blocksparse_matmul.blocksparse_matmul",
+    "flash_attention.flash_attention"])
+def test_kernel_modules_leave_interpret_to_ops(fn):
+    """No kernel wrapper defaults to interpret mode: ``kernels.ops`` alone
+    decides it from the running backend, so nothing reaches the chip
+    interpreted by omission."""
+    import importlib
+    import inspect
+    mod, name = fn.split(".")
+    f = getattr(importlib.import_module(f"repro.kernels.{mod}"), name)
+    param = inspect.signature(f).parameters["interpret"]
+    assert param.default is inspect.Parameter.empty
+
+
+def test_native_prox_refuses_unaligned_block_before_dispatch():
+    """A 64-wide tile of a 256-wide operand cannot lower for the TPU: the
+    wrapper says so with a ValueError instead of a compiler error (or a
+    silent reroute)."""
+    z = jnp.ones((256, 256), jnp.float32)
+    with pytest.raises(ValueError, match=r"\(8, 128\)"):
+        ops.fused_prox_stats(z, z, 0.1, block=(64, 64), interpret=False)

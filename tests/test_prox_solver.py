@@ -101,3 +101,30 @@ def test_nongaussian_data_still_recovers():
     r = fit_reference(jnp.asarray(p.s), 0.35, 0.02, tol=1e-5, max_iters=300)
     ppv, _ = graphs.ppv_fdr(np.asarray(r.omega), p.omega0)
     assert ppv > 0.7
+
+
+@pytest.mark.parametrize("variant", ["cov", "obs"])
+def test_use_pallas_runs_the_kernel_prox_without_sparse_matmul(
+        chain_problem, variant):
+    """use_pallas=True with the dense product route still runs the fused
+    prox kernel (a pallas_call in the solve) and matches the jnp prox."""
+    import jax
+
+    from repro.core import prox
+    from repro.core.penalty import PenaltySpec
+    p = chain_problem
+    data = jnp.asarray(p.s if variant == "cov" else p.x)
+    spec = PenaltySpec("l1", 0.15, 0.05)
+
+    def solve(use_pallas):
+        return prox._solve_reference(
+            data, spec, None, variant=variant, tol=1e-6, max_iters=300,
+            max_ls=30, warm_start_tau=False, sparse_matmul=None,
+            use_pallas=use_pallas)
+
+    jaxpr = str(jax.make_jaxpr(lambda: solve(True))())
+    assert "pallas_call" in jaxpr
+    r_k, r_j = solve(True), solve(False)
+    assert bool(r_k.converged) and bool(r_j.converged)
+    np.testing.assert_allclose(np.asarray(r_k.omega), np.asarray(r_j.omega),
+                               rtol=0, atol=1e-5)

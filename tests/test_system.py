@@ -55,3 +55,36 @@ def test_clustering_pipeline_beats_marginal_baseline():
             best = score, len(np.unique(ph))
     assert 0.0 < best[0] <= 1.0
     assert best[1] >= 2
+
+
+def test_compile_cache_keeps_a_set_directory(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the entry points change nothing:
+    JAX reads the variable itself."""
+    import jax
+
+    from repro.launch.cache import enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    """Without the variable the cache goes to one fixed, git-ignored
+    directory of the checkout (the same path on every run)."""
+    import os
+
+    import jax
+
+    from repro.launch import cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert cache.enable_compile_cache() == cache.DEFAULT_DIR
+        assert jax.config.jax_compilation_cache_dir == cache.DEFAULT_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cache.DEFAULT_DIR == os.path.join(repo, ".jax_cache")
+    with open(os.path.join(repo, ".gitignore"), encoding="utf-8") as f:
+        assert ".jax_cache/" in f.read().split()
