@@ -1,0 +1,2 @@
+"""Percent of the traced window with no op on the device (Obs fits)."""
+from harness.readers import idle_share as read  # noqa: F401
