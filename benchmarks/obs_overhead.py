@@ -3,7 +3,8 @@
 Runs the SAME p=512 float64 lam1 path twice on the reference backend —
 once at ``obs="off"`` and once at ``obs="summary"`` — and gates the
 relative wall-time overhead of the instrumented run below 2%.  The obs
-mode is host-side only (spans, counters, the cost-model feed); it is not
+mode is host-side only (the tracer's ring and the report telemetry; the
+profiler annotations run at every mode, "off" included); it is not
 a static argument of any jitted program, so both runs reuse the same
 compiled solver and the only cost the gate can see is the tracer's own
 bookkeeping.  The two paths must also be BIT-EXACT: instrumentation
@@ -67,8 +68,7 @@ def run(p: int = 512, n: int = 1024, points: int = 8, tol: float = 1e-6,
         jax.block_until_ready(res.reports[-1].omega)
         return res
 
-    # warmup: compile the shared programs AND pay the obs package's lazy
-    # first import outside the timed region
+    # warmup: compile the shared programs outside the timed region
     run_path("off")
     run_path("summary")
 

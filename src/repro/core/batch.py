@@ -75,6 +75,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import get_tracer
 from . import costmodel, matops
 from .penalty import PenaltySpec, normalize_penalty
 from .prox import (
@@ -91,41 +92,6 @@ from .prox import (
 _SOLVER_STATICS = ("variant", "tol", "max_iters", "max_ls", "warm_start_tau",
                    "tau_schedule")
 
-
-class _NoSpan:
-    """Do-nothing stand-in for a tracer span when obs is inactive."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def note(self, **attrs):
-        return self
-
-
-_NO_SPAN = _NoSpan()
-
-
-def _obs_span(name: str, **attrs):
-    """Tracer span IF the obs subsystem is active (``repro.obs.trace``
-    already imported and scoped by the caller's backend); the shared
-    no-op otherwise — the engine itself never imports ``repro.obs``, so
-    ``obs="off"`` runs are byte-identical to the pre-obs code path."""
-    import sys
-    tr = sys.modules.get("repro.obs.trace")
-    if tr is None:
-        return _NO_SPAN
-    return tr.get_tracer().span(name, cat="batch", level="trace", **attrs)
-
-
-def _obs_event(name: str, **attrs) -> None:
-    import sys
-    tr = sys.modules.get("repro.obs.trace")
-    if tr is not None:
-        tr.get_tracer().event(name, cat="batch", level="trace", **attrs)
 
 #: execution schedules of the batched engine
 BATCH_SCHEDULES = ("compact", "monolithic")
@@ -716,6 +682,7 @@ def _solve_compact(arr, spec, ridge, omega0, *, variant, tol, max_iters,
     statics = dict(variant=variant, tol=tol, max_iters=max_iters,
                    max_ls=max_ls, tau_schedule=tau_schedule, chunk=chunk,
                    stacked=stacked, tau_init=1.0, use_pallas=use_pallas)
+    tracer = get_tracer()
     results: list = [None] * b
     occupancy: list = []
     capacities: list = []
@@ -742,7 +709,8 @@ def _solve_compact(arr, spec, ridge, omega0, *, variant, tol, max_iters,
     for wave_idx, wave in enumerate(waves):
         ids = np.asarray(wave, np.int64)
         cap = _capacity(len(ids), b)
-        _obs_event("batch.wave", wave=wave_idx, lanes=len(ids))
+        tracer.event("batch.wave", cat="batch", level="trace",
+                     wave=wave_idx, lanes=len(ids))
         pad_idx = np.concatenate(
             [ids, np.full(cap - len(ids), ids[-1], np.int64)])
         real = jnp.asarray(np.arange(cap) < len(ids))
@@ -767,8 +735,9 @@ def _solve_compact(arr, spec, ridge, omega0, *, variant, tol, max_iters,
 
         while True:
             n_real = int(np.sum(cur_ids >= 0))  # ca: allow=CA106 (np host array)
-            with _obs_span("batch.segment", segment=segments,
-                           wave=wave_idx, lanes=n_real, cap=cap):
+            with tracer.span("batch.segment", cat="batch", level="trace",
+                             segment=segments, wave=wave_idx, lanes=n_real,
+                             cap=cap):
                 if gemm == "host":
                     state, occ = _host_chunk(arr_w, arr_np_w, ridge_w, state,
                                              spec_w, **statics)
@@ -776,31 +745,37 @@ def _solve_compact(arr, spec, ridge, omega0, *, variant, tol, max_iters,
                     state, occ = _path_chunk(arr_w, ridge_w, state, spec_w,
                                              **statics)
             segments += 1
-            occ_np = np.asarray(occ)
-            executed = occ_np[occ_np > 0]
-            # a chunk's recorded count includes duplicated pad lanes;
-            # clip to the real-lane count for an honest occupancy line
-            occupancy.extend(int(min(v, n_real)) for v in executed)
-            capacities.extend([cap] * len(executed))
-            done = harvest(state, cur_ids)
-            live = np.flatnonzero(~done)
+            # the chunk boundary: wait for the device, read the chunk's
+            # outputs back, repack the live lanes
+            with tracer.span("batch.wait", cat="batch", level="trace"):
+                jax.block_until_ready((state, occ))
+            with tracer.span("batch.harvest", cat="batch", level="trace"):
+                occ_np = np.asarray(occ)
+                executed = occ_np[occ_np > 0]
+                # a chunk's recorded count includes duplicated pad lanes;
+                # clip to the real-lane count for an honest occupancy line
+                occupancy.extend(int(min(v, n_real)) for v in executed)
+                capacities.extend([cap] * len(executed))
+                done = harvest(state, cur_ids)
+                live = np.flatnonzero(~done)
             if live.size == 0:
                 break
-            new_cap = _capacity(live.size, b)
-            slots = np.concatenate(
-                [live, np.full(new_cap - live.size, live[-1], np.int64)])
-            state = _take_lanes(state, slots)
-            real = jnp.asarray(np.arange(new_cap) < live.size)
-            state = state._replace(done=state.done | ~real)
-            if stacked:
-                arr_w = _take_lanes(arr_w, slots)
-                if gemm == "host":
-                    arr_np_w = _to_host(arr_w)
-            ridge_w = _take_lanes(ridge_w, slots)
-            spec_w = _take_lanes(spec_w, slots)
-            cur_ids = cur_ids[slots]
-            cur_ids[live.size:] = -1
-            cap = new_cap
+            with tracer.span("batch.repack", cat="batch", level="trace"):
+                new_cap = _capacity(live.size, b)
+                slots = np.concatenate(
+                    [live, np.full(new_cap - live.size, live[-1], np.int64)])
+                state = _take_lanes(state, slots)
+                real = jnp.asarray(np.arange(new_cap) < live.size)
+                state = state._replace(done=state.done | ~real)
+                if stacked:
+                    arr_w = _take_lanes(arr_w, slots)
+                    if gemm == "host":
+                        arr_np_w = _to_host(arr_w)
+                ridge_w = _take_lanes(ridge_w, slots)
+                spec_w = _take_lanes(spec_w, slots)
+                cur_ids = cur_ids[slots]
+                cur_ids[live.size:] = -1
+                cap = new_cap
 
         if pilot_lane >= 0 and wave is waves[0]:
             om_b = jnp.broadcast_to(

@@ -37,6 +37,7 @@ from typing import NamedTuple, Sequence
 import numpy as np
 
 from ..core.matops import panel_gram
+from ..obs.trace import get_tracer
 from .shards import ChunkSource, as_source
 from .transforms import StreamStats, Transform, get_transform
 
@@ -50,34 +51,6 @@ DEFAULT_PANEL = 512
 
 #: default resident-memory budget of the rank transform's column sweeps
 RANK_BUDGET_BYTES = 256 * 1024 * 1024
-
-
-class _NoSpan:
-    """Do-nothing stand-in for a tracer span when obs is inactive."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def note(self, **attrs):
-        return self
-
-
-_NO_SPAN = _NoSpan()
-
-
-def _obs_span(name: str, **attrs):
-    """Tracer span IF the obs subsystem is active (``repro.obs.trace``
-    already imported, mode scoped by the caller); the shared no-op
-    otherwise — the data layer never imports ``repro.obs`` itself."""
-    import sys
-    tr = sys.modules.get("repro.obs.trace")
-    if tr is None:
-        return _NO_SPAN
-    return tr.get_tracer().span(name, cat="data", level="trace", **attrs)
 
 
 class GramResult(NamedTuple):
@@ -163,8 +136,9 @@ class GramAccumulator:
                 f"chunk {self.n_chunks} contains non-finite values; refusing "
                 f"to fold NaN/Inf into the Gram")
         self.source_dtype = self.source_dtype or arr.dtype.name
-        with _obs_span("gram.chunk", chunk=self.n_chunks,
-                       rows=int(arr.shape[0]), p=int(arr.shape[1])):
+        with get_tracer().span("gram.chunk", cat="data", level="trace",
+                               chunk=self.n_chunks, rows=int(arr.shape[0]),
+                               p=int(arr.shape[1])):
             a64 = np.ascontiguousarray(arr, np.float64)
             m = a64.shape[0]
             # blocked panel products through the matops dispatch, f64 always

@@ -40,6 +40,7 @@ from ..core.costmodel import (
     enumerate_configs,
     tune,
 )
+from ..obs.trace import get_tracer
 from .config import SolverConfig
 from .report import FitReport
 
@@ -91,17 +92,20 @@ class Problem(NamedTuple):
                 np.integer)) or n_samples < 1):
             raise ValueError(f"n_samples must be a positive int, got "
                              f"{n_samples!r}")
+        tracer = get_tracer()
         if x is not None:
             x = jnp.asarray(x)
             if x.ndim != 2:
                 raise ValueError(f"x must be 2-D (n, p), got shape {x.shape}")
-            _require_finite("x", x)
+            with tracer.span("validate", cat="facade", level="trace"):
+                _require_finite("x", x)
         if s is not None:
             s = jnp.asarray(s)
             if s.ndim != 2 or s.shape[0] != s.shape[1]:
                 raise ValueError(f"s must be square (p, p), got {s.shape}")
-            _require_finite("s", s)
-            _require_symmetric(s)
+            with tracer.span("validate", cat="facade", level="trace"):
+                _require_finite("s", s)
+                _require_symmetric(s)
         if x is not None and s is not None and x.shape[1] != s.shape[0]:
             raise ValueError(
                 f"x has p={x.shape[1]} columns but s is {s.shape}")
@@ -270,25 +274,17 @@ def _offdiag_l1(omega) -> float:
 
 
 def _solve_with_obs(config: SolverConfig, backend: str, variant: str,
-                    solve, *, p: int, n: int, n_devices: int = 1,
-                    c_x: int = 1, c_omega: int = 1):
+                    solve, *, p: int, n: int, n_devices: int = 1):
     """Run ``solve()`` (returning a result with ``.omega``) under the
     configured observability level.
 
-    ``obs="off"`` is the exact pre-obs code path — ``repro.obs`` is never
-    imported, no tracer state exists.  Otherwise the solve runs inside a
-    span (at ``"trace"`` additionally split into the dispatch fence —
-    trace + compile + enqueue — and the ``block_until_ready`` execution
-    drain), the solve metrics feed the process registry, and the
-    host-boundary telemetry dict lands on the report.  Nothing here is
-    visible to jax tracing, so compiled programs and numerics are
-    identical at every level."""
-    if config.obs == "off":
-        t0 = time.perf_counter()
-        res = solve()
-        jax.block_until_ready(res.omega)
-        return res, time.perf_counter() - t0, None
-    from ..obs.trace import get_tracer
+    The solve runs inside the ``fit.<backend>`` span, split into the
+    dispatch fence (trace + compile + enqueue) and the
+    ``block_until_ready`` execution drain; at every level these reach a
+    running profiler.  Above ``obs="off"`` the spans also land in the
+    tracer's ring and the host-boundary telemetry dict lands on the
+    report.  Nothing here is visible to jax tracing, so compiled programs
+    and numerics are identical at every level."""
     tracer = get_tracer()
     with tracer.scoped(config.obs):
         t0 = time.perf_counter()
@@ -300,24 +296,16 @@ def _solve_with_obs(config: SolverConfig, backend: str, variant: str,
             with tracer.span("execute", level="trace", variant=variant):
                 jax.block_until_ready(res.omega)
         wall = time.perf_counter() - t0
-        iters, ls_total = int(res.iters), int(res.ls_total)
-        span.note(iters=iters, ls_total=ls_total,
-                  converged=bool(res.converged))
-        telemetry = {
-            "obs": config.obs,
-            "dispatch_s": t1 - t0,
-            "execute_s": wall - (t1 - t0),
-            "ls_per_iter": ls_total / max(iters, 1),
-            # the registry feed needs the OBSERVED density, and _report
-            # already scans the estimate for its nnz/occupancy columns —
-            # defer record_solve_cost to there so the p^2 host scan runs
-            # once, not twice (at p=512 the duplicate scan alone was the
-            # bulk of the obs="summary" overhead)
-            "_pending_cost": dict(
-                variant=variant, p=p, n=n, iters=iters, ls_total=ls_total,
-                n_devices=n_devices, c_x=c_x, c_omega=c_omega,
-                wall_s=wall),
-        }
+    if config.obs == "off":
+        return res, wall, None
+    iters, ls_total = int(res.iters), int(res.ls_total)
+    span.note(iters=iters, ls_total=ls_total, converged=bool(res.converged))
+    telemetry = {
+        "obs": config.obs,
+        "dispatch_s": t1 - t0,
+        "execute_s": wall - (t1 - t0),
+        "ls_per_iter": ls_total / max(iters, 1),
+    }
     return res, wall, telemetry
 
 
@@ -330,50 +318,48 @@ def _as_spec(penalty) -> PenaltySpec:
 def _report(res, *, lam1, lam2, wall, backend, variant, config=None,
             c_x=1, c_omega=1, n_devices=1, penalty=None,
             telemetry=None) -> FitReport:
-    g = float(res.g_final)
-    config = config or SolverConfig()
-    if penalty is None:
-        penalty = PenaltySpec("l1", lam1, lam2)
-    # Always compute the final estimate's occupancy post hoc: the solver's
-    # in-loop telemetry (res.block_density) reads 1.0 both for genuinely
-    # dense iterates AND whenever the policy was dropped downstream (e.g.
-    # a block size that does not tile the distributed shard), so it cannot
-    # back the report's density column on its own.  One nonzero scan feeds
-    # both the nnz/row and the block-occupancy columns.
-    om = np.asarray(res.omega)
-    nz = np.abs(om) > NNZ_TOL
-    nnz_per_row = max(1.0, float(nz.sum()) / om.shape[0])
-    if telemetry is not None and "_pending_cost" in telemetry:
-        # deferred obs registry feed (see _solve_with_obs): the density
-        # the cost model wants is exactly this scan's nnz/row
-        from ..obs.metrics import get_registry, record_solve_cost
-        pc = telemetry.pop("_pending_cost")
-        cost = record_solve_cost(get_registry(),
-                                 density=nnz_per_row / om.shape[0], **pc)
-        telemetry["flops"] = cost["flops"]
-        telemetry["words"] = cost["words"]
-    bs = config.sparse_block
-    edges = np.arange(0, om.shape[0], bs)
-    occ = np.add.reduceat(np.add.reduceat(nz, edges, axis=0),
-                          edges, axis=1) > 0
-    block_density = float(occ.mean())
-    return FitReport(
-        omega=res.omega,
-        lam1=float(lam1), lam2=float(lam2),
-        iters=int(res.iters), ls_total=int(res.ls_total),
-        converged=bool(res.converged),
-        stalled=bool(res.stalled),
-        objective=g + penalty_value_np(penalty, res.omega),
-        objective_smooth=g,
-        penalty=penalty.label(),
-        wall_time_s=float(wall),
-        backend=backend, variant=variant,
-        c_x=int(c_x), c_omega=int(c_omega), n_devices=int(n_devices),
-        nnz_per_row=nnz_per_row,
-        block_density=block_density,
-        sparse_matmul=config.sparse_matmul,
-        telemetry=telemetry,
-    )
+    tracer = get_tracer()
+    with tracer.span("report", cat="facade"):
+        g = float(res.g_final)
+        config = config or SolverConfig()
+        if penalty is None:
+            penalty = PenaltySpec("l1", lam1, lam2)
+        # Always compute the final estimate's occupancy post hoc: the
+        # solver's in-loop telemetry (res.block_density) reads 1.0 both for
+        # genuinely dense iterates AND whenever the policy was dropped
+        # downstream (e.g. a block size that does not tile the distributed
+        # shard), so it cannot back the report's density column on its
+        # own.  One nonzero scan feeds both the nnz/row and the
+        # block-occupancy columns.
+        with tracer.span("report.copy", cat="facade", level="trace"):
+            om = np.asarray(res.omega)
+        with tracer.span("report.scan", cat="facade", level="trace"):
+            nz = np.abs(om) > NNZ_TOL
+            nnz_per_row = max(1.0, float(nz.sum()) / om.shape[0])
+            bs = config.sparse_block
+            edges = np.arange(0, om.shape[0], bs)
+            occ = np.add.reduceat(np.add.reduceat(nz, edges, axis=0),
+                                  edges, axis=1) > 0
+            block_density = float(occ.mean())
+        with tracer.span("report.penalty", cat="facade", level="trace"):
+            objective = g + penalty_value_np(penalty, res.omega)
+        return FitReport(
+            omega=res.omega,
+            lam1=float(lam1), lam2=float(lam2),
+            iters=int(res.iters), ls_total=int(res.ls_total),
+            converged=bool(res.converged),
+            stalled=bool(res.stalled),
+            objective=objective,
+            objective_smooth=g,
+            penalty=penalty.label(),
+            wall_time_s=float(wall),
+            backend=backend, variant=variant,
+            c_x=int(c_x), c_omega=int(c_omega), n_devices=int(n_devices),
+            nnz_per_row=nnz_per_row,
+            block_density=block_density,
+            sparse_matmul=config.sparse_matmul,
+            telemetry=telemetry,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -449,8 +435,7 @@ def distributed_backend(problem: Problem, penalty, config: SolverConfig,
     try:
         res, wall, telemetry = _solve_with_obs(
             config, "distributed", variant, solve, p=problem.p,
-            n=problem.n, n_devices=n_dev, c_x=grid.c_x,
-            c_omega=grid.c_omega)
+            n=problem.n, n_devices=n_dev)
     finally:
         if watch is not None:
             watch.uninstall()

@@ -28,10 +28,11 @@ BATCH_GEMMS = ("auto", "xla", "host")
 
 BATCH_WARM_STARTS = (None, "pilot")
 
-#: runtime observability levels (``repro.obs``): "off" never imports the
-#: obs package; "summary" records coarse per-solve spans + metrics;
-#: "trace" adds fine spans (compile/execute split, segments, chunks) and
-#: arms the comm reconciliation watcher on distributed solves
+#: runtime observability levels (``repro.obs``): "off" records nothing in
+#: the tracer's ring (spans still reach a running profiler); "summary"
+#: records coarse per-solve spans; "trace" adds fine spans (dispatch/
+#: execute split, report steps, segments and their boundaries, chunks)
+#: and arms the comm reconciliation watcher on distributed solves
 OBS_MODES = ("off", "summary", "trace")
 
 
@@ -86,15 +87,17 @@ class SolverConfig:
                    warm-starts the rest from it (path mode); ``None`` runs
                    all lanes cold.
     obs            runtime observability (``repro.obs``): ``"off"``
-                   (default — the obs package is never even imported),
-                   ``"summary"`` (coarse per-solve spans, solve metrics
-                   and latency histograms; <2% wall overhead, gated by
+                   (default — nothing recorded in the tracer's ring; the
+                   spans are profiler annotations only), ``"summary"``
+                   (coarse per-solve spans and report telemetry; <2% wall
+                   overhead on the CPU, gated by
                    ``benchmarks/obs_overhead.py``) or ``"trace"`` (adds
-                   compile-vs-execute split spans, per-segment/chunk
-                   spans, and measured-vs-static comm reconciliation on
-                   distributed solves).  Purely host-side: never part of
-                   any jit static key, never traced — identical compiled
-                   programs and bit-exact results at every level.
+                   dispatch-vs-execute, report-step, per-segment/boundary
+                   and chunk spans, and measured-vs-static comm
+                   reconciliation on distributed solves).  Purely
+                   host-side: never part of any jit static key, never
+                   traced — identical compiled programs and bit-exact
+                   results at every level.
     penalty        penalty family as a string form parsed by
                    ``core.penalty.parse_penalty``: ``"l1"`` (default),
                    ``"elastic_net"``, ``"scad"``/``"scad:3.7"``,

@@ -31,12 +31,14 @@ from its BIC-best point, then a weighted stage-2 path over the same grid.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Iterable
 
 import numpy as np
 
 from ..core.penalty import PenaltySpec, adaptive_weights, as_penalty
+from ..obs.trace import get_tracer
 from .backends import Problem, get_backend
 from .config import SolverConfig
 from .report import FitReport, PathResult, pseudo_bic
@@ -55,6 +57,16 @@ def _validate_grid(lam1_grid) -> list[float]:
             raise ValueError(f"lam1_grid values must be finite and > 0, "
                              f"got {v}")
     return grid
+
+
+def _observed(method):
+    """Run a public entry point with the tracer scoped to the config's
+    ``obs`` level, so every span of the call follows it."""
+    @functools.wraps(method)
+    def wrapper(self, *args, **kwargs):
+        with get_tracer().scoped(self.config.obs):
+            return method(self, *args, **kwargs)
+    return wrapper
 
 
 class ConcordEstimator:
@@ -135,6 +147,7 @@ class ConcordEstimator:
         self.n_iter_ = report.iters
         return self
 
+    @_observed
     def fit(self, x, *, omega0=None, transform: str | None = None,
             chunk_rows: int | None = None) -> "ConcordEstimator":
         """Fit from observations (either variant works).
@@ -155,12 +168,14 @@ class ConcordEstimator:
         problem = Problem.from_data(x=x)
         return self._finish(self._solve(problem, self.penalty, omega0))
 
+    @_observed
     def fit_cov(self, s, *, n_samples: int | None = None,
                 omega0=None) -> "ConcordEstimator":
         """Fit from a (p, p) sample covariance (forces the Cov variant)."""
         problem = Problem.from_data(s=s, n_samples=n_samples)
         return self._finish(self._solve(problem, self.penalty, omega0))
 
+    @_observed
     def fit_gram(self, gram, *, omega0=None) -> "ConcordEstimator":
         """Fit from a streamed Gram (``data.compute_gram`` /
         ``distributed_gram`` / the ``launch.gram prep`` artifact).
@@ -209,44 +224,31 @@ class ConcordEstimator:
     def _run_path(self, problem: Problem, grid: list[float],
                   spec: PenaltySpec, mode: str, warm_start: bool,
                   score_bic: bool, s_mat):
-        if self.config.obs != "off":
-            from ..obs.trace import get_tracer
-            tracer = get_tracer()
-            with tracer.scoped(self.config.obs):
-                with tracer.span("fit_path", points=len(grid),
-                                 mode=mode) as span:
-                    reports, stats = self._run_path_inner(
-                        problem, grid, spec, mode, warm_start, score_bic,
-                        s_mat)
-                span.note(total_iters=sum(r.iters for r in reports))
-            return reports, stats
-        return self._run_path_inner(problem, grid, spec, mode, warm_start,
-                                    score_bic, s_mat)
-
-    def _run_path_inner(self, problem: Problem, grid: list[float],
-                        spec: PenaltySpec, mode: str, warm_start: bool,
-                        score_bic: bool, s_mat):
-        stats = None
-        if mode == "batched":
-            from .batch import batched_path_reports
-            reports, _, stats = batched_path_reports(
-                problem, grid, self.config, penalty=spec)
-        else:
-            reports = []
-            omega0 = None
-            for lam1 in grid:
-                rep = self._solve(problem, spec.with_lam1(lam1),
-                                  omega0 if warm_start else None)
-                reports.append(rep)
-                omega0 = rep.omega
-        if score_bic:
-            reports = [
-                dataclasses.replace(
-                    rep, bic=pseudo_bic(rep.omega, s_mat, problem.n))
-                for rep in reports
-            ]
+        with get_tracer().span("fit_path", points=len(grid),
+                               mode=mode) as span:
+            stats = None
+            if mode == "batched":
+                from .batch import batched_path_reports
+                reports, _, stats = batched_path_reports(
+                    problem, grid, self.config, penalty=spec)
+            else:
+                reports = []
+                omega0 = None
+                for lam1 in grid:
+                    rep = self._solve(problem, spec.with_lam1(lam1),
+                                      omega0 if warm_start else None)
+                    reports.append(rep)
+                    omega0 = rep.omega
+            if score_bic:
+                reports = [
+                    dataclasses.replace(
+                        rep, bic=pseudo_bic(rep.omega, s_mat, problem.n))
+                    for rep in reports
+                ]
+            span.note(total_iters=sum(r.iters for r in reports))
         return reports, stats
 
+    @_observed
     def fit_path(self, x=None, lam1_grid: Iterable[float] = (), *,
                  s=None, n_samples: int | None = None,
                  warm_start: bool = True,
@@ -355,6 +357,7 @@ class ConcordEstimator:
 
     # -- batched multi-problem solves -----------------------------------
 
+    @_observed
     def fit_batch(self, x=None, *, s=None, lam1=None, lam2=None,
                   penalty=None, omega0=None):
         """Solve stacked (B, ...) problems as one compiled batched program.

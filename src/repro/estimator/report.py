@@ -50,9 +50,8 @@ class FitReport:
                                 # this penalty's nonsmooth value
     telemetry: dict | None = None   # obs!="off" only: host-boundary solve
                                     # telemetry (dispatch vs execute wall
-                                    # split, analytic flop/word totals at
-                                    # the observed shape, mean ls trials
-                                    # per iteration); None when obs="off"
+                                    # split, mean ls trials per
+                                    # iteration); None when obs="off"
 
     def summary(self) -> str:
         dens = ""

@@ -25,7 +25,6 @@ requests and run each group as ONE compiled program:
 from __future__ import annotations
 
 import argparse
-import contextlib
 import time
 from typing import NamedTuple
 
@@ -113,6 +112,7 @@ def serve_concord(args):
     """
     from ..core import graphs
     from ..estimator import ConcordEstimator, SolverConfig, fit_batch
+    from ..obs.trace import get_tracer
 
     rng = np.random.default_rng(args.seed)
     reqs = [graphs.make_problem("chain", args.p, args.n,
@@ -125,12 +125,11 @@ def serve_concord(args):
                           tol=args.tol, max_iters=args.max_iters,
                           obs=obs_mode)
     bsz = max(1, args.batch)
-    tracer = registry = None
+    tracer = get_tracer()
+    tracer.set_mode(obs_mode)
+    registry = None
     if obs_mode != "off":
         from ..obs.metrics import get_registry
-        from ..obs.trace import get_tracer
-        tracer = get_tracer()
-        tracer.set_mode(obs_mode)
         registry = get_registry()
 
     # batched drain: difficulty/shape-bucketed groups, tail-padded to bsz
@@ -149,10 +148,8 @@ def serve_concord(args):
         xg = jnp.asarray(xs[idx])
         group_shapes.append(tuple(xg.shape))
         g0 = time.perf_counter()
-        group_span = (tracer.span("serve.group", cat="serve",
-                                  requests=len(group), batch=bsz)
-                      if tracer is not None else contextlib.nullcontext())
-        with group_span:
+        with tracer.span("serve.group", cat="serve", requests=len(group),
+                         batch=bsz):
             rep = fit_batch(x=xg, lam1=lam1s[idx],
                             lam2=args.lam2, config=config)
         gw = time.perf_counter() - g0
@@ -167,10 +164,9 @@ def serve_concord(args):
                                    ).observe(solve_wall[i])
                 registry.histogram("repro_serve_latency_seconds"
                                    ).observe(queue_wait[i] + solve_wall[i])
-            if tracer is not None:
-                tracer.event("serve.request", cat="serve", request=i,
-                             queue_wait_s=float(queue_wait[i]),
-                             solve_wall_s=float(solve_wall[i]))
+            tracer.event("serve.request", cat="serve", request=i,
+                         queue_wait_s=float(queue_wait[i]),
+                         solve_wall_s=float(solve_wall[i]))
     t_batched = time.time() - t0
 
     # sequential baseline: one compiled solve per request

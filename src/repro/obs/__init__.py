@@ -5,11 +5,11 @@ program, so enabling observability cannot change compiled executables or
 numerics):
 
   * :mod:`repro.obs.trace` — span/event tracer with an in-memory ring
-    buffer and JSONL / Chrome-trace (Perfetto ``trace_event``) exporters.
+    buffer and a JSONL exporter; every span is also a profiler
+    annotation ``concord.<name>``, on the timeline of the device ops.
   * :mod:`repro.obs.metrics` — counters, gauges and exponential-bucket
     latency histograms (p50/p95/p99) with Prometheus-text and JSON
-    snapshot exporters, plus flop/byte accounting fed from
-    :mod:`repro.core.costmodel`'s analytic formulas at observed shapes.
+    snapshot exporters.
   * :mod:`repro.obs.commwatch` — static-vs-measured communication
     reconciliation: the collective schedule of a distributed solve is
     extracted from its jaxpr at dispatch time, expanded with the solve's
@@ -17,9 +17,9 @@ numerics):
     count and bytes-on-wire equality against the analytic
     ``core.costmodel.comm_volume`` predictions (the CA303 contract).
 
-The estimator plumbs ``SolverConfig.obs = "off" | "summary" | "trace"``
-through every backend; ``"off"`` (the default) never imports this
-package at all.
+The estimator scopes the tracer to ``SolverConfig.obs = "off" |
+"summary" | "trace"`` for each call; at ``"off"`` (the default) the ring
+records nothing and the spans are profiler annotations only.
 """
 from __future__ import annotations
 

@@ -1,20 +1,21 @@
-"""``repro-obs`` — inspect, diff, export and gate observability artifacts.
+"""``repro-obs`` — inspect, diff and gate observability artifacts.
 
 Subcommands:
 
-  * ``print <trace>``       pretty-print a JSONL or Chrome-trace export
+  * ``print <trace>``       pretty-print a JSONL span export
   * ``diff <a> <b>``        per-span-name count/duration deltas between
-                            two trace exports (regression triage)
-  * ``export <in> <out>``   convert between the JSONL and Chrome-trace
-                            formats (by file extension: ``.jsonl`` vs
-                            ``.json``)
+                            two span exports (regression triage)
   * ``reconcile``           run a distributed solve on N forced host
                             devices with the comm watcher armed and
                             check measured == static comm bytes
                             per (prim, axes); exit 1 on ANY divergence
                             (the CI gate), optionally exporting the
-                            Perfetto trace and the reconciliation JSON
+                            spans (JSONL) and the reconciliation JSON
                             as artifacts.
+
+For a timeline of the spans beside the device ops, capture a profiler
+trace (``jax.profiler.trace(dir)``) and open it in Perfetto or
+TensorBoard: every span is there as ``concord.<name>``.
 
 ``reconcile`` must own the process: it sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count`` before jax loads,
@@ -28,18 +29,7 @@ import json
 import sys
 from collections import defaultdict
 
-from .trace import load_chrome, load_jsonl
-
-
-def _load_any(path: str):
-    """A trace export, whichever format: Chrome-trace JSON documents are
-    objects with a ``traceEvents`` key, JSONL files are one span/line."""
-    with open(path, encoding="utf-8") as f:
-        head = f.read(64).lstrip()
-    if head.startswith("{") and '"traceEvents"' in open(
-            path, encoding="utf-8").read(4096):
-        return load_chrome(path)
-    return load_jsonl(path)
+from .trace import load_jsonl
 
 
 def _by_name(spans) -> dict:
@@ -51,7 +41,7 @@ def _by_name(spans) -> dict:
 
 
 def cmd_print(args) -> int:
-    spans = _load_any(args.trace)
+    spans = load_jsonl(args.trace)
     print(f"{args.trace}: {len(spans)} events")
     for s in sorted(spans, key=lambda s: s.t_start):
         extras = " ".join(f"{k}={v}" for k, v in sorted(s.args.items()))
@@ -68,27 +58,13 @@ def cmd_print(args) -> int:
 
 
 def cmd_diff(args) -> int:
-    a, b = _by_name(_load_any(args.a)), _by_name(_load_any(args.b))
+    a, b = _by_name(load_jsonl(args.a)), _by_name(load_jsonl(args.b))
     print(f"{'span':<28} {'count A->B':>14} {'duration A->B (ms)':>26}")
     for name in sorted(set(a) | set(b)):
         ra = a.get(name, {"count": 0, "duration": 0.0})
         rb = b.get(name, {"count": 0, "duration": 0.0})
         print(f"{name:<28} {ra['count']:>6} -> {rb['count']:<5} "
               f"{ra['duration'] * 1e3:>11.3f} -> {rb['duration'] * 1e3:.3f}")
-    return 0
-
-
-def cmd_export(args) -> int:
-    from .trace import Tracer
-    spans = _load_any(args.src)
-    t = Tracer(mode="trace", capacity=max(len(spans), 1))
-    for s in spans:
-        t._record(s)
-    if args.dst.endswith(".jsonl"):
-        n = t.export_jsonl(args.dst)
-    else:
-        n = t.export_chrome(args.dst)
-    print(f"wrote {n} events to {args.dst}")
     return 0
 
 
@@ -133,7 +109,7 @@ def cmd_reconcile(args) -> int:
         print(rep.render())
         print()
     if args.trace_out:
-        tracer.export_chrome(args.trace_out)
+        tracer.export_jsonl(args.trace_out)
         print(f"trace -> {args.trace_out}")
     if args.json_out:
         with open(args.json_out, "w", encoding="utf-8") as f:
@@ -151,19 +127,14 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="repro-obs", description=__doc__)
     sub = ap.add_subparsers(dest="cmd", required=True)
 
-    p = sub.add_parser("print", help="pretty-print a trace export")
+    p = sub.add_parser("print", help="pretty-print a JSONL span export")
     p.add_argument("trace")
     p.set_defaults(fn=cmd_print)
 
-    p = sub.add_parser("diff", help="diff two trace exports by span name")
+    p = sub.add_parser("diff", help="diff two span exports by span name")
     p.add_argument("a")
     p.add_argument("b")
     p.set_defaults(fn=cmd_diff)
-
-    p = sub.add_parser("export", help="convert jsonl <-> chrome trace")
-    p.add_argument("src")
-    p.add_argument("dst")
-    p.set_defaults(fn=cmd_export)
 
     p = sub.add_parser(
         "reconcile",
@@ -180,7 +151,7 @@ def main(argv=None) -> int:
     p.add_argument("--variants", default="cov,obs",
                    help="comma list of cov/obs")
     p.add_argument("--trace-out", default=None,
-                   help="write the Perfetto trace here")
+                   help="write the recorded spans here (JSONL)")
     p.add_argument("--json-out", default=None,
                    help="write the reconciliation rows here")
     p.set_defaults(fn=cmd_reconcile)

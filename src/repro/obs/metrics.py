@@ -9,10 +9,6 @@ so p50/p95/p99 latency quantiles stay meaningful across six decades of
 solve time with O(64) cells; :meth:`Histogram.quantile` interpolates
 linearly inside the winning bucket, so on known samples it matches
 ``numpy.quantile`` to within one bucket's relative width (= ``growth``).
-
-Flop/byte work counters are fed from :mod:`repro.core.costmodel`'s
-analytic formulas (paper Lemma 3.4) evaluated at the *observed* problem
-shape, iteration count and density — see :func:`record_solve_cost`.
 """
 from __future__ import annotations
 
@@ -35,7 +31,7 @@ def _label_str(labels: tuple) -> str:
 
 @dataclass
 class Counter:
-    """Monotone accumulator (events, flops, bytes)."""
+    """Monotone accumulator (events, requests)."""
     name: str
     labels: tuple = ()
     value: float = 0.0
@@ -216,44 +212,6 @@ class MetricsRegistry:
                 lines.append(f"{name}_sum{_label_str(labels)} {m.sum:g}")
                 lines.append(f"{name}_count{_label_str(labels)} {m.total}")
         return "\n".join(lines) + "\n"
-
-
-# ---------------------------------------------------------------------------
-# analytic work accounting (costmodel formulas at observed shapes)
-# ---------------------------------------------------------------------------
-
-def record_solve_cost(registry: MetricsRegistry, *, variant: str,
-                      p: int, n: int | None, iters: int, ls_total: int,
-                      density: float = 1.0, n_devices: int = 1,
-                      c_x: int = 1, c_omega: int = 1,
-                      wall_s: float | None = None) -> dict:
-    """Feed the flop/word counters from the paper's Lemma 3.4 cost model
-    evaluated at the OBSERVED shape: ``s`` = outer iterations, ``t`` =
-    mean line-search trials per iteration, ``d`` = observed nnz/row.
-
-    Returns the computed ``{"flops", "words"}`` so callers can attach
-    them to telemetry without re-deriving."""
-    from ..core import costmodel
-
-    s = max(int(iters), 1)
-    t = max(float(ls_total) / s, 1.0)
-    # n is unknown when the caller handed a precomputed Gram (fit_cov
-    # without n_samples) — the solve then performs no Gram-formation
-    # flops, so the 2np^2 term is correctly zero
-    shape = costmodel.ProblemShape(p=p, n=n if n is not None else 0,
-                                   d=max(density * p, 1.0), s=s, t=t)
-    fn = costmodel.cov_costs if variant == "cov" else costmodel.obs_costs
-    cb = fn(shape, max(n_devices, 1), c_x, c_omega, costmodel.EDISON)
-    registry.counter("repro_solve_flops_total", variant=variant).inc(cb.flops)
-    registry.counter("repro_solve_comm_words_total",
-                     variant=variant).inc(cb.words)
-    registry.counter("repro_solves_total", variant=variant).inc()
-    registry.counter("repro_solve_iters_total", variant=variant).inc(iters)
-    registry.counter("repro_solve_ls_total", variant=variant).inc(ls_total)
-    if wall_s is not None:
-        registry.histogram("repro_solve_wall_seconds",
-                           variant=variant).observe(wall_s)
-    return {"flops": cb.flops, "words": cb.words}
 
 
 # ---------------------------------------------------------------------------

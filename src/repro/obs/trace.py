@@ -1,26 +1,31 @@
-"""Host-side span/event tracer with ring buffer and trace exporters.
+"""Host-side span/event tracer on the profiler's timeline.
 
-Spans mark host-boundary work (a solve dispatch, a compact-schedule
-segment, a Gram chunk update, a serve request); instant events mark
-points in time.  Everything is recorded on the host with
-``time.perf_counter`` — the tracer is never visible to jax tracing, so
-turning it on cannot change a compiled program or its numerics.
+Spans mark host-boundary work (input validation, a solve dispatch, the
+per-estimate report, a compact-schedule segment and its chunk boundary, a
+Gram chunk update, a serve request); instant events mark points in time.
+Every span enters a ``jax.profiler.TraceAnnotation`` named
+``concord.<span name>``, at every ``obs`` level, so a profiler trace
+(``jax.profiler.trace(dir)``) holds the program's host steps on the same
+clock as the device ops.  With no profiler running an annotation costs
+about a microsecond.  Nothing here is visible to jax tracing, so no level
+can change a compiled program or its numerics.
 
-Two verbosity levels nest the taxonomy:
+The ring buffer records a span only when the tracer's mode enables its
+level; it keeps ``time.perf_counter`` durations for
+``FitReport.telemetry`` and the JSONL export.  Two levels nest:
 
   * ``"summary"`` — one span per coarse unit of work (solve, path
-    point, request).  Cheap enough to leave on in production; the
-    overhead gate in ``benchmarks/obs_overhead.py`` holds it under 2%.
-  * ``"trace"``  — adds fine-grained spans (compile vs execute split,
-    per-segment chunk launches, per-chunk Gram updates).
+    point, report, request).  The overhead benchmark
+    (``benchmarks/obs_overhead.py``, an 8-point p=512 f64 path on the
+    CPU) holds it under 2% of wall time.
+  * ``"trace"``  — adds fine-grained spans (dispatch vs execute,
+    validation, the report's copy/scan/penalty steps, per-segment chunk
+    launches and their boundaries, per-chunk Gram updates).
 
-``mode="off"`` short-circuits every call through a shared no-op span —
-no allocation, no clock read.
+``mode="off"`` (the default) records nothing in the ring.
 
-Exporters: :meth:`Tracer.export_jsonl` (one JSON object per line) and
-:meth:`Tracer.export_chrome` (Perfetto / ``chrome://tracing``
-``trace_event`` JSON); :func:`load_chrome` and :func:`load_jsonl` read
-both back for round-trip tests and the ``repro-obs`` CLI.
+Export: :meth:`Tracer.export_jsonl` (one JSON object per line);
+:func:`load_jsonl` reads it back for the ``repro-obs`` CLI.
 """
 from __future__ import annotations
 
@@ -31,8 +36,14 @@ from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
+from jax.profiler import TraceAnnotation
+
 MODES = ("off", "summary", "trace")
 _LEVEL_RANK = {"off": 0, "summary": 1, "trace": 2}
+
+#: profiler-annotation prefix: keeps the program's spans apart from the
+#: Python tracer's frames and other annotations on the host plane
+ANNOTATION_PREFIX = "concord."
 
 #: ring-buffer capacity: old spans fall off rather than growing without
 #: bound in an always-on service
@@ -64,61 +75,35 @@ class Span:
             "level": self.level, "args": dict(self.args),
         }
 
-    def to_chrome(self, pid: int = 0, tid: int = 0) -> dict:
-        """Perfetto ``trace_event``: complete event ("X") for spans,
-        instant event ("i") for point events; timestamps in us."""
-        ev = {
-            "name": self.name, "cat": self.cat,
-            "ts": self.t_start * 1e6, "pid": pid, "tid": tid,
-            "args": {**self.args, "level": self.level},
-        }
-        if self.phase == "span":
-            ev["ph"] = "X"
-            ev["dur"] = self.duration * 1e6
-        else:
-            ev["ph"] = "i"
-            ev["s"] = "t"
-        return ev
 
+class _SpanScope:
+    """One span: a profiler annotation always, and a ring record on exit
+    when the tracer's mode enables the span's level (``span`` is then
+    the record, else None)."""
+    __slots__ = ("_tracer", "_span", "_annotation")
 
-class _NullSpan:
-    """Shared do-nothing span for disabled levels: supports the same
-    ``with``/``note`` surface with no allocation per call."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def note(self, **attrs):
-        return self
-
-
-_NULL_SPAN = _NullSpan()
-
-
-class _LiveSpan:
-    """Context manager recording one span into the tracer's ring on
-    exit (completion order; Chrome sorts by ``ts`` on import)."""
-    __slots__ = ("_tracer", "_span")
-
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", name: str, span: Span | None):
         self._tracer = tracer
         self._span = span
+        self._annotation = TraceAnnotation(ANNOTATION_PREFIX + name)
 
-    def __enter__(self) -> Span:
+    def __enter__(self):
+        self._annotation.__enter__()
+        if self._span is None:
+            return self
         self._span.t_start = time.perf_counter()
         return self._span
 
     def __exit__(self, *exc):
-        self._span.duration = time.perf_counter() - self._span.t_start
-        self._tracer._record(self._span)
+        if self._span is not None:
+            self._span.duration = time.perf_counter() - self._span.t_start
+            self._tracer._record(self._span)
+        self._annotation.__exit__(*exc)
         return False
 
     def note(self, **attrs):
-        self._span.note(**attrs)
+        if self._span is not None:
+            self._span.note(**attrs)
         return self
 
 
@@ -146,8 +131,8 @@ class Tracer:
 
     @contextmanager
     def scoped(self, mode: str):
-        """Temporarily run the tracer at ``mode`` (how a backend applies
-        ``SolverConfig.obs`` for the duration of one solve)."""
+        """Temporarily run the tracer at ``mode`` (how the facade applies
+        ``SolverConfig.obs`` for the duration of one call)."""
         prev = self._mode
         self.set_mode(mode)
         try:
@@ -158,11 +143,13 @@ class Tracer:
     # -- recording -------------------------------------------------------
     def span(self, name: str, *, cat: str = "solver",
              level: str = "summary", **attrs):
-        """``with tracer.span("fit", p=64) as s: ... s.note(iters=12)``"""
-        if not self.enabled(level):
-            return _NULL_SPAN
-        return _LiveSpan(self, Span(name=name, cat=cat, level=level,
-                                    args=dict(attrs)))
+        """``with tracer.span("fit", p=64) as s: ... s.note(iters=12)``
+
+        Enters the profiler annotation ``concord.<name>`` at every mode;
+        records into the ring only when the mode enables ``level``."""
+        span = (Span(name=name, cat=cat, level=level, args=dict(attrs))
+                if self.enabled(level) else None)
+        return _SpanScope(self, name, span)
 
     def event(self, name: str, *, cat: str = "solver",
               level: str = "summary", **attrs) -> None:
@@ -197,16 +184,6 @@ class Tracer:
                 f.write(json.dumps(s.to_json()) + "\n")
         return len(spans)
 
-    def export_chrome(self, path, *, pid: int = 0) -> int:
-        spans = self.snapshot()
-        doc = {
-            "displayTimeUnit": "ms",
-            "traceEvents": [s.to_chrome(pid=pid) for s in spans],
-        }
-        with open(path, "w", encoding="utf-8") as f:
-            json.dump(doc, f, indent=1)
-        return len(spans)
-
 
 def _span_from_json(d: dict) -> Span:
     return Span(name=d["name"], cat=d.get("cat", "solver"),
@@ -221,27 +198,8 @@ def load_jsonl(path) -> list:
                 for line in f if line.strip()]
 
 
-def load_chrome(path) -> list:
-    """Read a Chrome-trace export back into :class:`Span` records (the
-    inverse of :meth:`Tracer.export_chrome`, up to float round-trip)."""
-    with open(path, encoding="utf-8") as f:
-        doc = json.load(f)
-    spans = []
-    for ev in doc.get("traceEvents", ()):
-        args = dict(ev.get("args", ()))
-        level = args.pop("level", "summary")
-        spans.append(Span(
-            name=ev["name"], cat=ev.get("cat", "solver"),
-            t_start=ev["ts"] / 1e6,
-            duration=ev.get("dur", 0.0) / 1e6,
-            level=level,
-            phase="span" if ev.get("ph") == "X" else "instant",
-            args=args))
-    return spans
-
-
 # ---------------------------------------------------------------------------
-# process-global tracer (created lazily: obs="off" paths never touch it)
+# process-global tracer
 # ---------------------------------------------------------------------------
 
 _TRACER: Tracer | None = None

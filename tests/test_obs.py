@@ -1,19 +1,21 @@
 """Observability subsystem (``repro.obs``): tracer/metrics units, the
-exporter round-trips, estimator integration (bit-exactness at every obs
-level, zero extra compiles at ``obs="trace"``, lazy import at
-``obs="off"``), measured-vs-static comm reconciliation on 1 and 4
-devices, and the serve drain's queue-wait/solve-wall latency split."""
-import json
+JSONL round-trip, the spans on the profiler's host plane at every obs
+level, estimator integration (bit-exactness at every obs level, zero
+extra compiles, an empty ring at ``obs="off"``), measured-vs-static comm
+reconciliation on 1 and 4 devices, and the serve drain's
+queue-wait/solve-wall latency split."""
+import glob
+import os
+import subprocess
 import sys
 
 import numpy as np
 import pytest
 
-from conftest import run_with_devices
+from conftest import SRC, run_with_devices
 
-from repro.obs.metrics import (Counter, Histogram, MetricsRegistry,
-                               record_solve_cost)
-from repro.obs.trace import Tracer, load_chrome, load_jsonl
+from repro.obs.metrics import Histogram, MetricsRegistry
+from repro.obs.trace import ANNOTATION_PREFIX, MODES, Tracer, load_jsonl
 
 
 @pytest.fixture(autouse=True)
@@ -98,24 +100,159 @@ def test_jsonl_roundtrip(tmp_path):
         assert orig.to_json() == rt.to_json()
 
 
-def test_chrome_roundtrip(tmp_path):
-    t = Tracer(mode="trace")
-    with t.span("solve", cat="solver", p=16):
-        t.event("mark", level="trace", wave=2)
-    path = tmp_path / "trace.json"
-    assert t.export_chrome(path) == 2
-    doc = json.loads(path.read_text())
-    assert "traceEvents" in doc
-    back = load_chrome(path)
-    assert len(back) == 2
-    for orig, rt in zip(sorted(t.snapshot(), key=lambda s: s.t_start),
-                        sorted(back, key=lambda s: s.t_start)):
-        assert (orig.name, orig.cat, orig.phase,
-                orig.level) == (rt.name, rt.cat, rt.phase, rt.level)
-        # chrome timestamps are integer-microsecond; 1 us round-trip slop
-        assert abs(orig.t_start - rt.t_start) < 2e-6
-        assert abs(orig.duration - rt.duration) < 2e-6
-        assert {k: v for k, v in orig.args.items()} == rt.args
+# ---------------------------------------------------------------------------
+# the profiler's timeline
+# ---------------------------------------------------------------------------
+
+def _host_spans(trace_dir) -> list:
+    """(name, start_ns, end_ns) of the program's spans on the host plane
+    of the profiler trace written under ``trace_dir``, in start order."""
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(ANNOTATION_PREFIX):
+                    out.append((ev.name[len(ANNOTATION_PREFIX):],
+                                ev.start_ns, ev.start_ns + ev.duration_ns))
+    return sorted(out, key=lambda e: e[1])
+
+
+def _inside(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_spans_reach_the_profiler_at_every_mode(tmp_path, mode):
+    """Every span is a ``concord.``-prefixed profiler annotation whatever
+    the mode; the ring keeps only the levels the mode enables."""
+    import jax
+
+    t = Tracer(mode=mode)
+    with jax.profiler.trace(str(tmp_path)):
+        with t.span("outer"):
+            with t.span("inner", level="trace") as s:
+                s.note(k=1)
+    spans = _host_spans(tmp_path)
+    assert [n for n, _, _ in spans] == ["outer", "inner"]
+    assert _inside(spans[1], spans[0])
+    ring = {"off": [], "summary": ["outer"], "trace": ["inner", "outer"]}
+    assert [s.name for s in t.snapshot()] == ring[mode]
+
+
+def test_fit_puts_facade_steps_on_the_profiler_at_obs_off(tmp_path):
+    """A default (``obs="off"``) fit from X shows the finite check, the
+    dispatch/execute split and the report's copy, scan and penalty steps,
+    each inside the whole-report span, and records nothing in the ring."""
+    import jax
+
+    from repro.core import graphs
+    from repro.estimator import ConcordEstimator, SolverConfig
+    from repro.obs.trace import get_tracer
+
+    prob = graphs.make_problem("chain", 24, 64, seed=0)
+    est = ConcordEstimator(lam1=0.2, lam2=0.05, config=SolverConfig(
+        backend="reference", variant="obs", tol=1e-5, max_iters=60))
+    est.fit(prob.x)                     # compile outside the trace
+    get_tracer().clear()
+    with jax.profiler.trace(str(tmp_path)):
+        est.fit(prob.x)
+    spans = _host_spans(tmp_path)
+    names = [n for n, _, _ in spans]
+    for name in ("validate", "fit.reference", "dispatch", "execute",
+                 "report", "report.copy", "report.scan", "report.penalty"):
+        assert names.count(name) == 1, (name, names)
+    by_name = {sp[0]: sp for sp in spans}
+    for step in ("report.copy", "report.scan", "report.penalty"):
+        assert _inside(by_name[step], by_name["report"])
+    assert _inside(by_name["dispatch"], by_name["fit.reference"])
+    assert by_name["validate"][2] <= by_name["fit.reference"][1]
+    assert by_name["fit.reference"][2] <= by_name["report"][1]
+    assert len(get_tracer()) == 0
+
+
+def test_batched_path_puts_each_chunk_boundary_on_the_profiler(tmp_path):
+    """The compact engine's boundaries: one wait and one harvest after
+    every segment, a repack wherever lanes stay live, and one report per
+    grid point, all at ``obs="off"``."""
+    import jax
+
+    from repro.core import graphs
+    from repro.estimator import ConcordEstimator, SolverConfig
+
+    prob = graphs.make_problem("chain", 20, 48, seed=0)
+    cfg = SolverConfig(backend="reference", variant="cov", tol=1e-4,
+                       max_iters=60, batch_chunk=4)
+    est = ConcordEstimator(penalty="l1", config=cfg)
+    grid = [0.3, 0.2, 0.1]
+
+    def path():
+        return est.fit_path(s=prob.s, lam1_grid=grid, n_samples=48,
+                            score_bic=False, mode="batched")
+
+    path()                              # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        stats = path().batch_stats
+    spans = _host_spans(tmp_path)
+    names = [n for n, _, _ in spans]
+    assert stats.segments > stats.waves
+    for name in ("batch.segment", "batch.wait", "batch.harvest"):
+        assert names.count(name) == stats.segments, name
+    assert names.count("batch.repack") == stats.segments - stats.waves
+    assert names.count("report") == len(grid)
+    path_span, = (sp for sp in spans if sp[0] == "fit_path")
+    assert all(_inside(sp, path_span) for sp in spans
+               if sp[0].startswith(("batch.", "report")))
+    waits = [sp for sp in spans if sp[0] == "batch.wait"]
+    harvests = [sp for sp in spans if sp[0] == "batch.harvest"]
+    assert all(w[2] <= h[1] for w, h in zip(waits, harvests))
+
+
+def test_gram_chunks_reach_the_profiler(tmp_path):
+    import jax
+
+    from repro.data.gram import compute_gram
+
+    rng = np.random.default_rng(0)
+    chunks = [rng.standard_normal((16, 8)) for _ in range(3)]
+    with jax.profiler.trace(str(tmp_path)):
+        gram = compute_gram(iter(chunks))
+    names = [n for n, _, _ in _host_spans(tmp_path)]
+    assert names.count("gram.chunk") == gram.n_chunks == 3
+
+
+@pytest.mark.parametrize("obs, expected", [
+    ("summary", {"fit.reference", "report"}),
+    ("trace", {"fit.reference", "dispatch", "execute", "validate",
+               "report", "report.copy", "report.scan", "report.penalty"}),
+])
+def test_obs_level_selects_the_ring_spans_of_a_fit(obs, expected):
+    from repro.obs.trace import get_tracer
+
+    tracer = get_tracer()
+    tracer.clear()
+    _fit(obs)
+    assert {s.name for s in tracer.snapshot()} == expected
+    assert all(s.duration >= 0 for s in tracer.snapshot())
+
+
+def test_reconcile_writes_its_spans_as_jsonl(tmp_path):
+    out = tmp_path / "reconcile.jsonl"
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run(
+        [sys.executable, "-m", "repro.obs.cli", "reconcile", "--devices",
+         "2", "--p", "16", "--n", "24", "--max-iters", "3",
+         "--variants", "cov", "--trace-out", str(out)],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stdout + proc.stderr[-4000:]
+    names = {s.name for s in load_jsonl(out)}
+    assert "reconcile.cov" in names
 
 
 # ---------------------------------------------------------------------------
@@ -191,23 +328,6 @@ def test_prometheus_text_exposition():
     assert snap['repro_solve_wall_seconds{variant="cov"}']["count"] == 3
 
 
-def test_record_solve_cost_feeds_costmodel_counters():
-    reg = MetricsRegistry()
-    out = record_solve_cost(reg, variant="cov", p=64, n=128, iters=10,
-                            ls_total=14, density=0.2, wall_s=0.05)
-    assert out["flops"] > 0 and out["words"] >= 0
-    assert reg.counter("repro_solves_total", variant="cov").value == 1
-    assert reg.counter("repro_solve_iters_total", variant="cov").value == 10
-    # n=None (precomputed Gram): no Gram-formation flops, still positive
-    out2 = record_solve_cost(reg, variant="cov", p=64, n=None, iters=10,
-                             ls_total=14, density=0.2)
-    assert 0 < out2["flops"] < out["flops"]
-    # obs variant uses the other closed form
-    out3 = record_solve_cost(reg, variant="obs", p=64, n=128, iters=10,
-                             ls_total=14, density=0.2)
-    assert out3["flops"] > 0
-
-
 # ---------------------------------------------------------------------------
 # estimator integration
 # ---------------------------------------------------------------------------
@@ -236,7 +356,6 @@ def test_obs_levels_are_bit_exact_and_carry_telemetry():
         assert rep.iters == base.iters and rep.ls_total == base.ls_total
         tele = rep.telemetry
         assert tele["obs"] == obs
-        assert tele["flops"] > 0 and tele["words"] >= 0
         assert tele["dispatch_s"] >= 0 and tele["execute_s"] >= 0
         assert "_pending_cost" not in tele
 
@@ -247,31 +366,55 @@ def test_obs_config_validation():
         SolverConfig(obs="verbose")
 
 
-def test_obs_off_never_imports_the_obs_package():
-    run_with_devices("""
-import sys
-import numpy as np
-from repro.core import graphs
-from repro.estimator import ConcordEstimator, SolverConfig
-prob = graphs.make_problem("chain", 16, 40, seed=0)
-cfg = SolverConfig(backend="reference", variant="cov", tol=1e-4,
-                   max_iters=40, obs="off")
-ConcordEstimator(lam1=0.2, config=cfg).fit_cov(prob.s)
-loaded = [m for m in sys.modules if m.startswith("repro.obs")]
-assert not loaded, f"obs='off' pulled in {loaded}"
-print("OK")
-""", n_devices=1, timeout=300)
+def test_obs_off_records_nothing_and_changes_no_program(recompile_guard):
+    """``obs="off"`` records nothing in the ring, even with the process
+    tracer left at "trace" by someone else, and runs the same compiled
+    programs to the same estimates as an instrumented call."""
+    from repro.core import batch, graphs, prox
+    from repro.estimator import ConcordEstimator, SolverConfig
+    from repro.obs.trace import get_tracer
+
+    prob = graphs.make_problem("chain", 20, 48, seed=0)
+
+    def path(obs):
+        cfg = SolverConfig(backend="reference", variant="cov", tol=1e-4,
+                           max_iters=60, batch_chunk=4, obs=obs)
+        return ConcordEstimator(penalty="l1", config=cfg).fit_path(
+            s=prob.s, lam1_grid=[0.3, 0.1], n_samples=48,
+            score_bic=False, mode="batched")
+
+    fit_traced, path_traced = _fit("trace"), path("trace")
+    tracer = get_tracer()
+    tracer.set_mode("trace")
+    tracer.clear()
+    with recompile_guard(solve=prox._solve_reference,
+                         chunk=batch._path_chunk):
+        fit_off, path_off = _fit("off"), path("off")
+    assert len(tracer) == 0
+    assert fit_off.telemetry is None
+    np.testing.assert_array_equal(np.asarray(fit_off.omega),
+                                  np.asarray(fit_traced.omega))
+    for a, b in zip(path_off.reports, path_traced.reports):
+        np.testing.assert_array_equal(np.asarray(a.omega),
+                                      np.asarray(b.omega))
 
 
 def test_obs_trace_adds_zero_compiles(recompile_guard):
     from repro.core import prox
 
-    _fit("trace")      # compile once (and pay the lazy obs import)
+    from repro.obs.trace import get_tracer
+
+    base = _fit("trace")      # compile once
     _fit("off")
+    tracer = get_tracer()
     with recompile_guard(solve=prox._solve_reference):
         _fit("trace")
         _fit("summary")
-        _fit("off")
+        n_recorded = len(tracer)
+        off = _fit("off")
+    assert len(tracer) == n_recorded > 0
+    np.testing.assert_array_equal(np.asarray(off.omega),
+                                  np.asarray(base.omega))
 
 
 def test_fit_path_telemetry_and_span():
