@@ -22,6 +22,7 @@ Pallas solver) without touching the estimator.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Callable, NamedTuple
 
@@ -166,11 +167,48 @@ def _variant_candidates(problem: Problem, config: SolverConfig) -> tuple:
     return variants
 
 
+class OmegaSummary(NamedTuple):
+    """What a report reads of a (p, p) estimate, reduced on the device."""
+    nnz_rows: jax.Array         # (p,) int32: entries with |Ω_ij| > tol
+    occupied: jax.Array         # int32: block x block tiles holding one
+    offdiag_l1_rows: jax.Array  # (p,) Ω's dtype: Σ_{j != i} |Ω_ij|
+
+
+@functools.partial(jax.jit, static_argnames=("block", "tol"))
+def omega_summary(omega, *, block: int, tol: float) -> OmegaSummary:
+    """Summarise an estimate where it lives, in its own dtype.
+
+    Per-row counts keep the total exact past 2**31 entries (the host sums
+    them in int64); partial edge tiles count as zero-padded.  Occupancy
+    reduces the rows of each tile first, a reshape of the leading axis
+    that needs no relayout on the TPU.  One program per (p, dtype,
+    block); a sharded Ω is reduced in place."""
+    p = omega.shape[0]
+    nb = -(-p // block)
+    pad = nb * block - p
+    a = jnp.abs(omega)
+    nz = a > tol
+    row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    rows = jnp.pad(nz, ((0, pad), (0, 0))) if pad else nz
+    cols = jnp.any(rows.reshape(nb, block, p), axis=1)
+    cols = jnp.pad(cols, ((0, 0), (0, pad))) if pad else cols
+    tiles = jnp.any(cols.reshape(nb, nb, block), axis=2)
+    return OmegaSummary(
+        nnz_rows=jnp.sum(nz, axis=1, dtype=jnp.int32),
+        occupied=jnp.sum(tiles, dtype=jnp.int32),
+        offdiag_l1_rows=jnp.sum(jnp.where(row == col, 0, a), axis=1))
+
+
+def _nnz_per_row(nnz_rows) -> float:
+    return max(1.0, float(np.sum(nnz_rows, dtype=np.int64)) / len(nnz_rows))
+
+
 def observed_nnz_per_row(omega) -> float:
     """Average nonzeros per row of an iterate (the cost model's ``d``)."""
-    om = np.asarray(omega)
-    return max(1.0, float(np.count_nonzero(np.abs(om) > NNZ_TOL))
-               / om.shape[0])
+    summary = omega_summary(jnp.asarray(omega),
+                            block=SolverConfig.sparse_block, tol=NNZ_TOL)
+    return _nnz_per_row(jax.device_get(summary.nnz_rows))
 
 
 def _problem_shape(problem: Problem, lam1: float,
@@ -268,11 +306,6 @@ def _resolve_variant(problem: Problem, lam1: float, config: SolverConfig,
     return _check_grid(best.variant, best.c_x, best.c_omega, n_devices)
 
 
-def _offdiag_l1(omega) -> float:
-    om = np.asarray(omega)
-    return float(np.sum(np.abs(om)) - np.sum(np.abs(np.diag(om))))
-
-
 def _solve_with_obs(config: SolverConfig, backend: str, variant: str,
                     solve, *, p: int, n: int, n_devices: int = 1):
     """Run ``solve()`` (returning a result with ``.omega``) under the
@@ -320,7 +353,6 @@ def _report(res, *, lam1, lam2, wall, backend, variant, config=None,
             telemetry=None) -> FitReport:
     tracer = get_tracer()
     with tracer.span("report", cat="facade"):
-        g = float(res.g_final)
         config = config or SolverConfig()
         if penalty is None:
             penalty = PenaltySpec("l1", lam1, lam2)
@@ -329,33 +361,38 @@ def _report(res, *, lam1, lam2, wall, backend, variant, config=None,
         # genuinely dense iterates AND whenever the policy was dropped
         # downstream (e.g. a block size that does not tile the distributed
         # shard), so it cannot back the report's density column on its
-        # own.  One nonzero scan feeds both the nnz/row and the
-        # block-occupancy columns.
-        with tracer.span("report.copy", cat="facade", level="trace"):
-            om = np.asarray(res.omega)
-        with tracer.span("report.scan", cat="facade", level="trace"):
-            nz = np.abs(om) > NNZ_TOL
-            nnz_per_row = max(1.0, float(nz.sum()) / om.shape[0])
-            bs = config.sparse_block
-            edges = np.arange(0, om.shape[0], bs)
-            occ = np.add.reduceat(np.add.reduceat(nz, edges, axis=0),
-                                  edges, axis=1) > 0
-            block_density = float(occ.mean())
-        with tracer.span("report.penalty", cat="facade", level="trace"):
-            objective = g + penalty_value_np(penalty, res.omega)
+        # own.  One device summary feeds the nnz/row and block-occupancy
+        # columns and the l1 penalty; one fetch brings it back with the
+        # solver's scalars, and Ω stays on the device.
+        bs = config.sparse_block
+        with tracer.span("report.summary", cat="facade", level="trace"):
+            summary = omega_summary(res.omega, block=bs, tol=NNZ_TOL)
+            summary, g, iters, ls_total, converged, stalled, pen_lam1 = \
+                jax.device_get((summary, res.g_final, res.iters,
+                                res.ls_total, res.converged, res.stalled,
+                                penalty.lam1))
+        g = float(g)
+        nb = -(-res.omega.shape[0] // bs)
+        block_density = int(summary.occupied) / (nb * nb)
+        if penalty.kind in ("l1", "elastic_net"):
+            objective = g + float(np.asarray(pen_lam1)) * float(
+                np.sum(summary.offdiag_l1_rows, dtype=np.float64))
+        else:
+            with tracer.span("report.penalty", cat="facade", level="trace"):
+                objective = g + penalty_value_np(penalty, res.omega)
         return FitReport(
             omega=res.omega,
             lam1=float(lam1), lam2=float(lam2),
-            iters=int(res.iters), ls_total=int(res.ls_total),
-            converged=bool(res.converged),
-            stalled=bool(res.stalled),
+            iters=int(iters), ls_total=int(ls_total),
+            converged=bool(converged),
+            stalled=bool(stalled),
             objective=objective,
             objective_smooth=g,
             penalty=penalty.label(),
             wall_time_s=float(wall),
             backend=backend, variant=variant,
             c_x=int(c_x), c_omega=int(c_omega), n_devices=int(n_devices),
-            nnz_per_row=nnz_per_row,
+            nnz_per_row=_nnz_per_row(summary.nnz_rows),
             block_density=block_density,
             sparse_matmul=config.sparse_matmul,
             telemetry=telemetry,

@@ -20,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import prox
 from repro.core.penalty import as_penalty
 from repro.estimator import SolverConfig
-from repro.estimator.backends import _matmul_policy
+from repro.estimator.backends import NNZ_TOL, _matmul_policy, omega_summary
 from repro.kernels import ops, pathstep, softthresh
 
 
@@ -122,3 +122,14 @@ def test_unaligned_p_path_step_refuses_before_dispatch(chip):
 
     with pytest.raises(ValueError, match="multiple of 128"):
         jax.jit(run).lower(chip(c, p, p), chip(c, p, p), chip(c), chip(c))
+
+
+@pytest.mark.parametrize("p", [22528, 3000])
+def test_omega_summary_compiles_for_v5e(chip, p):
+    """The report's device summary at the largest cell's p and at a p that
+    is not a multiple of the tile: its temporaries come to a one-byte
+    mask of Ω and its small partial sums (a relayout of Ω would take 2-4
+    times that)."""
+    compiled = omega_summary.lower(chip(p, p), block=128,
+                                   tol=NNZ_TOL).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= p * p + 2**20

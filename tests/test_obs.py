@@ -147,8 +147,9 @@ def test_spans_reach_the_profiler_at_every_mode(tmp_path, mode):
 
 def test_fit_puts_facade_steps_on_the_profiler_at_obs_off(tmp_path):
     """A default (``obs="off"``) fit from X shows the finite check, the
-    dispatch/execute split and the report's copy, scan and penalty steps,
-    each inside the whole-report span, and records nothing in the ring."""
+    dispatch/execute split and the report's device summary inside the
+    whole-report span, no host penalty step for the l1 penalty, and
+    records nothing in the ring."""
     import jax
 
     from repro.core import graphs
@@ -165,11 +166,11 @@ def test_fit_puts_facade_steps_on_the_profiler_at_obs_off(tmp_path):
     spans = _host_spans(tmp_path)
     names = [n for n, _, _ in spans]
     for name in ("validate", "fit.reference", "dispatch", "execute",
-                 "report", "report.copy", "report.scan", "report.penalty"):
+                 "report", "report.summary"):
         assert names.count(name) == 1, (name, names)
+    assert "report.penalty" not in names
     by_name = {sp[0]: sp for sp in spans}
-    for step in ("report.copy", "report.scan", "report.penalty"):
-        assert _inside(by_name[step], by_name["report"])
+    assert _inside(by_name["report.summary"], by_name["report"])
     assert _inside(by_name["dispatch"], by_name["fit.reference"])
     assert by_name["validate"][2] <= by_name["fit.reference"][1]
     assert by_name["fit.reference"][2] <= by_name["report"][1]
@@ -229,7 +230,7 @@ def test_gram_chunks_reach_the_profiler(tmp_path):
 @pytest.mark.parametrize("obs, expected", [
     ("summary", {"fit.reference", "report"}),
     ("trace", {"fit.reference", "dispatch", "execute", "validate",
-               "report", "report.copy", "report.scan", "report.penalty"}),
+               "report", "report.summary"}),
 ])
 def test_obs_level_selects_the_ring_spans_of_a_fit(obs, expected):
     from repro.obs.trace import get_tracer
@@ -239,6 +240,35 @@ def test_obs_level_selects_the_ring_spans_of_a_fit(obs, expected):
     _fit(obs)
     assert {s.name for s in tracer.snapshot()} == expected
     assert all(s.duration >= 0 for s in tracer.snapshot())
+
+
+@pytest.mark.parametrize("penalty, host_penalty", [
+    ("l1", False), ("elastic_net", False), ("scad", True), ("mcp", True),
+])
+def test_report_penalty_span_only_for_host_penalties(tmp_path, penalty,
+                                                     host_penalty):
+    """The l1 kinds take their penalty from the device summary; only the
+    other kinds run the host penalty, each inside the report."""
+    import jax
+
+    from repro.core import graphs
+    from repro.estimator import ConcordEstimator, SolverConfig
+
+    prob = graphs.make_problem("chain", 24, 64, seed=0)
+    est = ConcordEstimator(penalty=penalty, lam1=0.2, lam2=0.05,
+                           config=SolverConfig(backend="reference",
+                                               variant="cov", tol=1e-5,
+                                               max_iters=60))
+    est.fit_cov(prob.s, n_samples=64)   # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        est.fit_cov(prob.s, n_samples=64)
+    spans = _host_spans(tmp_path)
+    names = [n for n, _, _ in spans]
+    assert names.count("report.summary") == 1
+    assert names.count("report.penalty") == int(host_penalty)
+    report, = (sp for sp in spans if sp[0] == "report")
+    assert all(_inside(sp, report) for sp in spans
+               if sp[0].startswith("report."))
 
 
 def test_reconcile_writes_its_spans_as_jsonl(tmp_path):
